@@ -3,7 +3,7 @@
 The paper's step 3 computes above-ground-level altitude for every
 observation: AGL = MSL - DEM(lat, lon). On CPU/GPU this is a 4-point
 gather from the elevation raster. Fine-grained gathers are the worst case
-for the TPU memory system, so we adapt (DESIGN.md §2):
+for the TPU memory system, so we adapt:
 
   1. *Spatial locality*: one aircraft track covers a tiny DEM window
      (§V: per-sensor tracks bound the DEM working set — the paper calls
@@ -11,10 +11,10 @@ for the TPU memory system, so we adapt (DESIGN.md §2):
      prefetch one (TH, TW) DEM tile into VMEM, selected by a per-track
      block origin carried as scalar-prefetch operands.
   2. *Gather -> matmul*: bilinear interpolation of M points from a VMEM
-     tile is computed as  rowsum((A @ tile) * Ct)  where A (M, TH) holds
-     the row weights (1-di, di) at columns (i0, i0+1) and Ct (M, TW) the
-     column weights. One MXU matmul + one VPU reduction replace M
-     scattered 4-point gathers.
+     tile is computed as  colsum(A * (tile @ C))  where A (TH, M) holds
+     the row weights (1-di, di) at rows (i0, i0+1) and C (TW, M) the
+     column weights, points on the lanes. One MXU matmul + one VPU
+     reduction replace M scattered 4-point gathers.
 
 Tracks wider than a tile are clamped to its border; ops.py routes such
 tracks (rare, detected on host) to the jnp oracle instead.
@@ -35,10 +35,11 @@ TILE_W = 256
 
 def _kernel(oi_ref, oj_ref, fi_ref, fj_ref, alt_ref, dem_ref, out_ref):
     # Scalar prefetch: oi/oj (B,) block-origin indices (in tiles).
+    # Blocks (row dim squeezed): fi/fj/alt/out (1, M), dem (TH, TW).
     b = pl.program_id(0)
-    fi = fi_ref[0, :]                       # (M,) fractional rows (global)
-    fj = fj_ref[0, :]
-    alt = alt_ref[0, :]
+    fi = fi_ref[...]                        # (1, M) fractional rows (global)
+    fj = fj_ref[...]
+    alt = alt_ref[...]
     tile = dem_ref[...]                     # (TH, TW) VMEM tile
 
     # Tile-local coordinates, clamped inside the tile.
@@ -51,18 +52,21 @@ def _kernel(oi_ref, oj_ref, fi_ref, fj_ref, alt_ref, dem_ref, out_ref):
     di = fi_loc - i0.astype(jnp.float32)
     dj = fj_loc - j0.astype(jnp.float32)
 
-    M = fi.shape[0]
-    rows = jax.lax.broadcasted_iota(jnp.int32, (M, TILE_H), 1)
-    cols = jax.lax.broadcasted_iota(jnp.int32, (M, TILE_W), 1)
-    # Bilinear weights as sparse one-hot-pair matrices.
-    A = (jnp.where(rows == i0[:, None], 1.0 - di[:, None], 0.0)
-         + jnp.where(rows == i0[:, None] + 1, di[:, None], 0.0))
-    Ct = (jnp.where(cols == j0[:, None], 1.0 - dj[:, None], 0.0)
-          + jnp.where(cols == j0[:, None] + 1, dj[:, None], 0.0))
-    # (M, TH) @ (TH, TW) -> (M, TW); weighted row-sum -> (M,)
-    rowsel = jnp.dot(A, tile, preferred_element_type=jnp.float32)
-    elev = jnp.sum(rowsel * Ct, axis=1)
-    out_ref[0, :] = alt - elev
+    M = fi.shape[1]
+    rows = jax.lax.broadcasted_iota(jnp.int32, (TILE_H, M), 0)
+    cols = jax.lax.broadcasted_iota(jnp.int32, (TILE_W, M), 0)
+    # Bilinear weights as sparse one-hot-pair matrices, points on lanes.
+    a_t = (jnp.where(rows == i0, 1.0 - di, 0.0)
+           + jnp.where(rows == i0 + 1, di, 0.0))           # (TH, M)
+    c_t = (jnp.where(cols == j0, 1.0 - dj, 0.0)
+           + jnp.where(cols == j0 + 1, dj, 0.0))           # (TW, M)
+    # (TH, TW) @ (TW, M) -> (TH, M) column-interpolated rows; then the
+    # row-weighted sublane sum -> (1, M).  HIGHEST: a bf16 pass would
+    # round terrain heights by metres.
+    colsel = jnp.dot(tile, c_t, precision=jax.lax.Precision.HIGHEST,
+                     preferred_element_type=jnp.float32)
+    elev = jnp.sum(a_t * colsel, axis=0, keepdims=True)
+    out_ref[...] = alt - elev
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -72,30 +76,33 @@ def agl_lookup_pallas(dem: jax.Array, fi: jax.Array, fj: jax.Array,
     """AGL altitudes for B tracks of M points each.
 
     dem (H, W) f32 — H, W multiples of TILE_H/TILE_W (ops.py pads);
-    fi/fj/alt_msl (B, M) f32 — global fractional DEM indices + MSL (m);
-    oi/oj (B,) i32 — per-track tile origins, in tile units.
-    Returns (B, M) f32 AGL (m).
+    fi/fj/alt_msl (B, M) f32 — global fractional DEM indices + MSL (m),
+    M a multiple of 128; oi/oj (B,) i32 — per-track tile origins, in
+    tile units.  Returns (B, M) f32 AGL (m).
     """
     B, M = fi.shape
     H, W = dem.shape
     if H % TILE_H or W % TILE_W:
         raise ValueError(f"dem {dem.shape} not tile-aligned")
+    # Point rows become a unit sublane axis so each (1, M) block matches
+    # the array's last two dims (the TPU tiling rule) for any B.
+    row = pl.BlockSpec((None, 1, M), lambda b, oi, oj: (b, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(B,),
         in_specs=[
-            pl.BlockSpec((1, M), lambda b, oi, oj: (b, 0)),
-            pl.BlockSpec((1, M), lambda b, oi, oj: (b, 0)),
-            pl.BlockSpec((1, M), lambda b, oi, oj: (b, 0)),
+            row, row, row,
             pl.BlockSpec((TILE_H, TILE_W), lambda b, oi, oj: (oi[b], oj[b])),
         ],
-        out_specs=pl.BlockSpec((1, M), lambda b, oi, oj: (b, 0)),
+        out_specs=row,
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         _kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, M), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((B, 1, M), jnp.float32),
         interpret=interpret,
     )(oi.astype(jnp.int32), oj.astype(jnp.int32),
-      fi.astype(jnp.float32), fj.astype(jnp.float32),
-      alt_msl.astype(jnp.float32), dem.astype(jnp.float32))
+      fi.astype(jnp.float32).reshape(B, 1, M),
+      fj.astype(jnp.float32).reshape(B, 1, M),
+      alt_msl.astype(jnp.float32).reshape(B, 1, M), dem.astype(jnp.float32))
+    return out.reshape(B, M)

@@ -11,9 +11,11 @@ Three numerically identical execution paths share one chunked pair
 trace (:func:`_chunk_minima`):
 
   * ``backend="pallas"`` — the fused kernel: one program per
-    (cell, 8-row block) streams the time axis in 128-sample chunks,
-    keeping (rows, K) running minima in registers.  Interpret mode on
-    CPU, compiled on TPU (same convention as :mod:`ops`).
+    (cell, 128-sample time chunk, 8-row block), folding each chunk into
+    (K, K) running minima that stay resident in VMEM for the whole
+    cell, so VMEM use does not grow with the cell's time span.
+    Compiled on a TPU, interpreted elsewhere
+    (:func:`repro.device.interpret_kernels`).
   * ``backend="jit"`` — the same chunked trace XLA-compiled over the
     whole (C, K, T) batch; the production CPU path.
   * ``backend="ref"`` — :func:`repro.kernels.ref.encounter_screen_ref`
@@ -45,6 +47,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 
+from repro.device import interpret_kernels
 from repro.geometry.gridhash import CellKey, GridSpec, bin_samples
 from repro.kernels.ref import encounter_screen_ref
 from repro.tracks.segments import BUCKET_SIZES, _round_rows, bucket_width
@@ -61,10 +64,6 @@ _M_PER_DEG = 111_111.0
 _T_CHUNK = 128                  # lane-width time chunks
 _ROW_BLOCK = 8                  # f32 sublane tile: 8 pair rows per program
 _C_CHUNK_BYTES = 64 << 20       # cap jnp-path (C, K, K, Tc) intermediates
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 # ---------------------------------------------------------------------------
@@ -118,57 +117,52 @@ def _fold_chunk(carry, chunk, t_base):
 def _screen_kernel(lat_ref, lon_ref, alt_ref, val_ref,
                    hit_ref, dh_ref, dv_ref, ti_ref, *,
                    h_m: float, v_m: float, rb: int, tc: int):
-    ib = pl.program_id(1)
-    lat = lat_ref[0]            # (K, T)
-    lon = lon_ref[0]
-    alt = alt_ref[0]
-    val = val_ref[0]
-    K, T = lat.shape
-    i0 = ib * rb
+    # Grid (cell, time chunk, row block).  Input blocks are one (K, tc)
+    # time chunk of the cell; the (K, K) outputs stay resident in VMEM
+    # across the chunk and row-block axes and carry the running minima.
+    tk = pl.program_id(1)
+    i0 = pl.multiple_of(pl.program_id(2) * rb, rb)
+    rows = pl.ds(i0, rb)
+    K = lat_ref.shape[1]
 
-    def rows(x):
-        return jax.lax.dynamic_slice(x, (i0, 0), (rb, T))
+    @pl.when(tk == 0)
+    def _init():
+        hit_ref[0, rows, :] = jnp.zeros((rb, K), jnp.float32)
+        dh_ref[0, rows, :] = jnp.full((rb, K), _BIG, jnp.float32)
+        dv_ref[0, rows, :] = jnp.full((rb, K), _BIG, jnp.float32)
+        ti_ref[0, rows, :] = jnp.zeros((rb, K), jnp.float32)
 
-    lat_i, lon_i, alt_i, val_i = rows(lat), rows(lon), rows(alt), rows(val)
-    i_ids = i0 + jax.lax.broadcasted_iota(jnp.int32, (rb, K), 0)
-    j_ids = jax.lax.broadcasted_iota(jnp.int32, (rb, K), 1)
-    tri = (i_ids < j_ids)[:, :, None]
+    shape3 = (rb, K, lat_ref.shape[2])
+    tri = (i0 + jax.lax.broadcasted_iota(jnp.int32, shape3, 0)
+           < jax.lax.broadcasted_iota(jnp.int32, shape3, 1))
 
-    def body(c, carry):
-        t0 = c * tc
+    def ci(ref):        # (rb, 1, tc)
+        return ref[0, rows, :][:, None, :]
 
-        def ci(x):      # (rb, 1, tc)
-            return jax.lax.dynamic_slice(x, (0, t0), (rb, tc))[:, None, :]
+    def cj(ref):        # (1, K, tc)
+        return ref[0][None, :, :]
 
-        def cj(x):      # (1, K, tc)
-            return jax.lax.dynamic_slice(x, (0, t0), (K, tc))[None, :, :]
-
-        chunk = _chunk_minima(ci(lat_i), ci(lon_i), ci(alt_i), ci(val_i),
-                              cj(lat), cj(lon), cj(alt), cj(val),
-                              tri, h_m, v_m)
-        return _fold_chunk(carry, chunk, t0)
-
-    init = (jnp.zeros((rb, K), jnp.float32),
-            jnp.full((rb, K), _BIG, jnp.float32),
-            jnp.full((rb, K), _BIG, jnp.float32),
-            jnp.zeros((rb, K), jnp.float32))
-    hit, mdh, mdv, tix = jax.lax.fori_loop(0, T // tc, body, init)
-    hit_ref[0] = hit
-    dh_ref[0] = mdh
-    dv_ref[0] = mdv
-    ti_ref[0] = tix
+    chunk = _chunk_minima(ci(lat_ref), ci(lon_ref), ci(alt_ref),
+                          ci(val_ref), cj(lat_ref), cj(lon_ref),
+                          cj(alt_ref), cj(val_ref), tri, h_m, v_m)
+    carry = (hit_ref[0, rows, :], dh_ref[0, rows, :], dv_ref[0, rows, :],
+             ti_ref[0, rows, :])
+    hit, mdh, mdv, tix = _fold_chunk(carry, chunk, tk * tc)
+    hit_ref[0, rows, :] = hit
+    dh_ref[0, rows, :] = mdh
+    dv_ref[0, rows, :] = mdv
+    ti_ref[0, rows, :] = tix
 
 
 def _screen_batch_pallas(lat, lon, alt, val, *, h_m, v_m, interpret):
     C, K, T = lat.shape
     rb, tc = _ROW_BLOCK, min(_T_CHUNK, T)
-    n_i = K // rb
-    in_spec = pl.BlockSpec((1, K, T), lambda c, i: (c, 0, 0))
-    out_spec = pl.BlockSpec((1, rb, K), lambda c, i: (c, i, 0))
+    in_spec = pl.BlockSpec((1, K, tc), lambda c, t, i: (c, 0, t))
+    out_spec = pl.BlockSpec((1, K, K), lambda c, t, i: (c, 0, 0))
     shape = jax.ShapeDtypeStruct((C, K, K), jnp.float32)
     return pl.pallas_call(
         functools.partial(_screen_kernel, h_m=h_m, v_m=v_m, rb=rb, tc=tc),
-        grid=(C, n_i),
+        grid=(C, T // tc, K // rb),
         in_specs=[in_spec] * 4,
         out_specs=[out_spec] * 4,
         out_shape=[shape] * 4,
@@ -254,8 +248,7 @@ reset_screen_stats()
 # ---------------------------------------------------------------------------
 
 def screen_aligned(lat, lon, alt, valid, *, h_thresh_m: float,
-                   v_thresh_m: float, backend: str = "jit",
-                   interpret: Optional[bool] = None) -> dict:
+                   v_thresh_m: float, backend: str = "jit") -> dict:
     """Screen a (C, K, T) batch of time-aligned cells.
 
     Pads rows to the 8-row tile, time to 128-sample chunks, and the
@@ -267,7 +260,7 @@ def screen_aligned(lat, lon, alt, valid, *, h_thresh_m: float,
     C, K, T = lat.shape
     Kp = max(_ROW_BLOCK, _round_rows(K))
     Tp = -(-T // _T_CHUNK) * _T_CHUNK
-    interp = (not _on_tpu()) if interpret is None else interpret
+    interp = interpret_kernels()
 
     def pad(x, fill=0.0):
         out = np.full((C, Kp, Tp), fill, np.float32)

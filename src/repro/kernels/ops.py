@@ -1,14 +1,14 @@
 """Public jit'd wrappers for the track-processing kernels.
 
 Each op pads inputs to kernel-friendly shapes, dispatches to the Pallas
-kernel (interpret mode on CPU, compiled on TPU) or to the pure-jnp oracle
+kernel (compiled on a TPU, interpreted elsewhere: see
+:func:`repro.device.interpret_kernels`) or to the pure-jnp oracle
 (``backend='ref'``), and unpads the result. The segments pipeline and the
 benchmarks call these, never the kernels directly.
 """
 
 from __future__ import annotations
 
-import functools
 import threading
 from typing import Literal
 
@@ -16,6 +16,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.device import interpret_kernels, on_tpu
 from repro.kernels import ref, segment_pipeline
 from repro.kernels.agl_lookup import TILE_H, TILE_W, agl_lookup_pallas
 from repro.kernels.dynamic_rates import dynamic_rates_pallas
@@ -23,10 +24,6 @@ from repro.kernels.flash_attention import flash_attention_pallas
 from repro.kernels.track_interp import track_interp_pallas
 
 Backend = Literal["pallas", "ref"]
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 # ---------------------------------------------------------------------------
@@ -53,6 +50,16 @@ def reset_pipeline_stats(forget_shapes: bool = True) -> None:
 def get_pipeline_stats() -> dict:
     with _STATS_LOCK:
         return dict(_STATS)
+
+
+def get_pipeline_shapes() -> list[dict]:
+    """The distinct fused-pipeline programs requested since the last
+    reset, one dict each: argument shapes, DEM grid, ``dt``, backend
+    and AGL variant (the keys of :func:`process_segments`' cache)."""
+    names = ("dem", "t_in", "t_out", "grid", "dt", "use_pallas",
+             "agl_oracle")
+    with _STATS_LOCK:
+        return [dict(zip(names, key)) for key in sorted(_SEEN_FUSED_SHAPES)]
 
 
 def note_intermediate_transfer(n: int = 1) -> None:
@@ -84,8 +91,8 @@ def track_interp(t_in, v_in, count, t_out, *,
     t_in_p = _pad_to(jnp.asarray(t_in, jnp.float32), 1, 128, value=np.inf)
     v_in_p = _pad_to(jnp.asarray(v_in, jnp.float32), 2, 128)
     out = track_interp_pallas(t_in_p, v_in_p, count, t_out_p,
-                              block_m=block_m, interpret=not _on_tpu())
-    return out[:, :M, :]
+                              block_m=block_m, interpret=interpret_kernels())
+    return jnp.moveaxis(out, 1, 2)[:, :M, :]
 
 
 def dynamic_rates(v, count, dt, *, backend: Backend = "pallas"):
@@ -95,7 +102,7 @@ def dynamic_rates(v, count, dt, *, backend: Backend = "pallas"):
     M = v.shape[2]
     v_p = _pad_to(jnp.asarray(v, jnp.float32), 2, 128)
     out = dynamic_rates_pallas(v_p, count, float(dt),
-                               interpret=not _on_tpu())
+                               interpret=interpret_kernels())
     return out[:, :, :M]
 
 
@@ -152,7 +159,7 @@ def agl_lookup(dem, fi, fj, alt_msl, *, backend: Backend = "pallas",
     alt_p = _pad_to(jnp.asarray(alt_np[fit]), 1, 128)
     out_fit = agl_lookup_pallas(dem_p, fi_p, fj_p, alt_p,
                                 jnp.asarray(oi), jnp.asarray(oj),
-                                interpret=not _on_tpu())[:, :M]
+                                interpret=interpret_kernels())[:, :M]
     if not spans.any():
         return out_fit
     out_spanning = _agl_lookup_ref_jit(dem, fi_c[spans], fj_c[spans],
@@ -207,7 +214,7 @@ def process_segments(dem, t_in, v_in, count_in, t_out, count_out, *,
     return segment_pipeline.process_segments(
         dem, t_in, v_in, count_in, t_out, count_out, grid=grid, dt=dt,
         use_pallas=use_pallas, agl_oracle=agl_oracle,
-        interpret=not _on_tpu(), donate=_on_tpu())
+        interpret=interpret_kernels(), donate=on_tpu())
 
 
 def flash_attention(q, k, v, *, causal: bool = True,
@@ -231,7 +238,7 @@ def flash_attention(q, k, v, *, causal: bool = True,
     out = flash_attention_pallas(q_p, k_p, v_p, causal=causal,
                                  block_q=bq, block_k=bk,
                                  q_len=T, kv_len=S,
-                                 interpret=not _on_tpu())
+                                 interpret=interpret_kernels())
     return out[:, :, :T]
 
 

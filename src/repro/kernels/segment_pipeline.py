@@ -79,7 +79,8 @@ def _pipeline(dem, t_in, v_in, count_in, t_out, count_out,
         interp = track_interp_pallas(t_in, v_in, count_in, t_out,
                                      block_m=block_m, interpret=interpret)
     else:
-        interp = ref.track_interp_ref(t_in, v_in, count_in, t_out)
+        interp = jnp.moveaxis(
+            ref.track_interp_ref(t_in, v_in, count_in, t_out), 2, 1)
     # Stage-boundary barrier: the unfused path materializes the interp
     # result on the host before the AGL/rates stages consume it, so its
     # f32 roundings are those of the standalone ops.  Without the
@@ -87,10 +88,10 @@ def _pipeline(dem, t_in, v_in, count_in, t_out, count_out,
     # and drift the fused outputs an ulp off the unfused golden path.
     # (On TPU the stage is a pallas_call boundary anyway; this costs
     # nothing material and buys bit-stable fused==unfused numerics.)
-    interp = jax.lax.optimization_barrier(interp)
-    lat = interp[..., 0]
-    lon = interp[..., 1]
-    alt = interp[..., 2]
+    interp = jax.lax.optimization_barrier(interp)           # (B, 3, K)
+    lat = interp[:, 0]
+    lon = interp[:, 1]
+    alt = interp[:, 2]
 
     # 2. DEM fractional indices from the affine grid — previously host
     #    numpy between two kernel launches; now VPU elementwise.  The
@@ -127,12 +128,11 @@ def _pipeline(dem, t_in, v_in, count_in, t_out, count_out,
         agl = ref.agl_lookup_ref(dem, fi, fj, alt)
 
     # 4. Dynamic rates over the resampled grid (VPU stencil kernel).
-    v_grid = jnp.moveaxis(interp, 2, 1)                      # (B, 3, K)
     if use_pallas:
-        rates = dynamic_rates_pallas(v_grid, count_out, dt,
+        rates = dynamic_rates_pallas(interp, count_out, dt,
                                      interpret=interpret)
     else:
-        rates = ref.dynamic_rates_ref(v_grid, count_out, dt)
+        rates = ref.dynamic_rates_ref(interp, count_out, dt)
 
     # 5. Padding masks, still on device.
     mask = (jax.lax.broadcasted_iota(jnp.int32, (B, K), 1)

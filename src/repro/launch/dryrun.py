@@ -42,7 +42,7 @@ from repro.distribution.sharding import (
     batch_shardings, batch_spec, cache_shardings, make_spec,
     opt_state_shardings, param_shardings)
 from repro.core.messages import Task
-from repro.launch.mesh import make_production_mesh, mesh_context
+from repro.launch.mesh import make_production_mesh
 from repro.launch import steps
 from repro.models import model as M
 from repro.roofline.hlo_parse import collective_bytes
@@ -149,7 +149,7 @@ def run_cell(cfg: ArchConfig, shape: ShapeConfig, multi_pod: bool,
     mesh = make_production_mesh(multi_pod=multi_pod)
     rec["chips"] = mesh.devices.size
     try:
-        with mesh_context(mesh):
+        with jax.set_mesh(mesh):
             pspecs = steps.param_specs(cfg)
             psh = param_shardings(pspecs, mesh)
             batch = steps.input_specs(cfg, shape)

@@ -5,7 +5,7 @@ importing this module never touches jax device state — required because
 smoke tests must see 1 CPU device while the dry-run forces 512.
 
 The triples-mode bridge: ``mesh_from_triples`` maps the paper's
-(nodes, NPPN, threads) launch triple onto mesh axes (DESIGN.md §2) —
+(nodes, NPPN, threads) launch triple onto mesh axes —
 nodes -> pod axis, NPPN -> data axis, threads x chips -> model axis.
 """
 
@@ -38,29 +38,6 @@ def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
 def make_mesh(shape: tuple[int, ...], axes: tuple[str, ...]) -> jax.sharding.Mesh:
     """Arbitrary mesh (elastic re-mesh after worker loss uses this)."""
     return jax.make_mesh(shape, axes)
-
-
-def mesh_context(mesh: jax.sharding.Mesh):
-    """Version-portable ``with mesh_context(mesh):`` block.
-
-    jax >= 0.5 spells it ``jax.set_mesh(mesh)``; on 0.4.x the Mesh object
-    itself is the context manager.
-    """
-    set_mesh = getattr(jax, "set_mesh", None)
-    if set_mesh is not None:
-        return set_mesh(mesh)
-    return mesh
-
-
-def make_abstract_mesh(shape: tuple[int, ...],
-                       axes: tuple[str, ...]):
-    """Version-portable AbstractMesh (axis-size/axis-name signature on
-    new jax; ((name, size), ...) tuple on 0.4.x)."""
-    from jax.sharding import AbstractMesh
-    try:
-        return AbstractMesh(shape, axes)            # jax >= 0.5
-    except TypeError:
-        return AbstractMesh(tuple(zip(axes, shape)))  # jax 0.4.x
 
 
 def mesh_from_triples(cfg: TriplesConfig, chips_per_node: int = 4,

@@ -5,7 +5,8 @@ behind the unified self-scheduling runtime
 (:func:`repro.runtime.run_job`), with a JSON phase checkpoint so a
 killed job resumes where it left off.  The execution backend is
 pluggable: ``threads`` (default) or ``processes`` (real NPPN-style
-process isolation); periodic *mid-phase* manager checkpoints mean a
+process isolation, CPU only: on a TPU one process owns the chip, so
+the device phases refuse it); periodic *mid-phase* manager checkpoints mean a
 kill-and-restart resumes inside a phase, not just at phase boundaries.
 This is the real (scaled-down) counterpart of the simulated full-scale
 benchmarks.
@@ -60,6 +61,7 @@ import json
 import os
 from typing import Optional
 
+from repro import device
 from repro.core.messages import Task
 from repro.core.triples import TriplesConfig
 from repro.geometry.aerodromes import synthetic_aerodromes
@@ -421,6 +423,12 @@ class TrackWorkflow:
         if policy not in POLICY_NAMES:
             raise ValueError(f"unknown scheduling policy {policy!r}; "
                              f"choose from {list(POLICY_NAMES)}")
+        if exec_backend == "processes" and device.on_tpu():
+            raise ValueError(
+                "exec_backend='processes' cannot run the device phases "
+                "(process, screen) on a TPU: each worker process would "
+                "need the chip, which one process owns at a time (see "
+                "ROADMAP B.1); use exec_backend='threads'")
         if elastic:
             if exec_backend != "threads":
                 raise ValueError("--elastic needs exec_backend='threads' "
@@ -1006,6 +1014,7 @@ def main() -> None:
                          "summary; feed either file to "
                          "`python -m repro.obs.report`)")
     args = ap.parse_args()
+    device.enable_compile_cache()
 
     tracer = None
     if args.trace:

@@ -29,7 +29,6 @@ from repro.configs.base import ArchConfig
 from repro.distribution.sharding import (
     batch_shardings, opt_state_shardings, param_shardings)
 from repro.launch import steps as step_lib
-from repro.launch.mesh import mesh_context
 from repro.models import model as M
 from repro.train import checkpoint as ckpt_lib
 from repro.train.optimizer import OptimizerConfig, init_opt_state
@@ -96,7 +95,7 @@ class Trainer:
             self.params = restored["params"]
             self.opt_state = restored["opt"]
         else:
-            with mesh_context(mesh):
+            with jax.set_mesh(mesh):
                 self.params = jax.jit(
                     functools.partial(M.init_params, cfg),
                     out_shardings=self.psh)(jax.random.key(self.seed))
@@ -140,7 +139,7 @@ class Trainer:
         n_steps = n_steps or self.tcfg.total_steps
         bsh = None
         target = self.step + n_steps
-        with mesh_context(self.mesh):
+        with jax.set_mesh(self.mesh):
             for batch in batches:
                 if self.step >= target:
                     break

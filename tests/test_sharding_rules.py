@@ -10,21 +10,21 @@ import jax
 import numpy as np
 import pytest
 from hypothesis import given, settings
+from jax.sharding import AbstractMesh
 from jax.sharding import PartitionSpec as P
 
 from repro.configs import all_arch_names, get_arch
 from repro.distribution.sharding import (
     batch_spec, cache_shardings, make_spec, param_shardings)
 from repro.launch import steps
-from repro.launch.mesh import make_abstract_mesh
 
 
 def mesh16x16():
-    return make_abstract_mesh((16, 16), ("data", "model"))
+    return AbstractMesh((16, 16), ("data", "model"))
 
 
 def mesh2x16x16():
-    return make_abstract_mesh((2, 16, 16), ("pod", "data", "model"))
+    return AbstractMesh((2, 16, 16), ("pod", "data", "model"))
 
 
 def _spec_divides(spec, shape, mesh) -> bool:

@@ -146,6 +146,18 @@ def test_workflow_runs_on_process_backend(tmp_path):
     assert all(r.tasks > 0 for r in reports)
 
 
+def test_process_backend_refused_on_tpu(tmp_path, monkeypatch):
+    """On a TPU one process owns the chip: the device phases refuse
+    worker processes up front, before any phase runs."""
+    from repro import device
+    monkeypatch.setattr(device, "on_tpu", lambda: True)
+    with pytest.raises(ValueError, match=r"\(process, screen\).*B\.1"):
+        TrackWorkflow(str(tmp_path), exec_backend="processes",
+                      input="store", screen=True)
+    assert not os.listdir(tmp_path)
+    TrackWorkflow(str(tmp_path), exec_backend="threads")
+
+
 def test_workflow_checkpoint_resume(tmp_path):
     wf = TrackWorkflow(str(tmp_path), n_workers=2, poll_interval=0.003)
     wf.generate_raw(n_files=3, scale=2e4)
