@@ -84,6 +84,11 @@ class Message:
     # surfaced per worker in RunResult so BENCH artifacts can attribute
     # time to scheduling vs I/O.
     wait_seconds: float = 0.0
+    # (start, seconds, CPU seconds of the worker thread) of each task in
+    # task_ids, on the worker's time.monotonic clock: the manager's exec
+    # spans.  A batched message's one call is split evenly over its
+    # tasks by the worker.
+    task_spans: tuple[tuple[float, float, float], ...] = ()
     error: Optional[str] = None
     sent_at: float = dataclasses.field(default_factory=time.monotonic)
 

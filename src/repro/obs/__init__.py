@@ -14,6 +14,8 @@ Entry points:
     ``run_job``/``run_dag``/``run_service``/``TrackStore``/
     ``IngestService``/``StoreFrontEnd``, or use ``--trace DIR`` on the
     track workflow CLI);
+  * :func:`stage` — a stage span inside a task, also opened as a
+    ``jax.profiler.TraceAnnotation`` (a no-op without a tracer);
   * :func:`build_summary` / :func:`summary_from_tracer` — canonical
     ``repro.obs/v1`` summaries;
   * :func:`to_chrome_trace` / :func:`from_chrome_trace` — Perfetto
@@ -30,10 +32,11 @@ import os
 from repro.obs.perfetto import from_chrome_trace, to_chrome_trace
 from repro.obs.summary import build_summary, phase_of, summary_from_tracer
 from repro.obs.tracer import (
-    CATEGORIES, DEFAULT_CAPACITY, EVENT_FIELDS, INSTANT, Tracer)
+    CATEGORIES, DEFAULT_CAPACITY, EVENT_FIELDS, INSTANT, NULL_STAGE, Tracer,
+    stage)
 
 __all__ = ["Tracer", "INSTANT", "EVENT_FIELDS", "CATEGORIES",
-           "DEFAULT_CAPACITY", "build_summary", "summary_from_tracer",
+           "DEFAULT_CAPACITY", "NULL_STAGE", "stage", "build_summary", "summary_from_tracer",
            "phase_of", "to_chrome_trace", "from_chrome_trace",
            "write_trace_files"]
 

@@ -20,7 +20,12 @@ Determinism: timestamps are normalized to the earliest event, every
 reduction iterates in event order or over sorted keys, and no wall-clock
 or environment field enters the document — so a sim trace summarizes to
 byte-identical JSON across same-seed reruns
-(``repro.bench.schema.canonical_bytes`` is the serializer).
+(``repro.bench.schema.canonical_bytes`` is the serializer).  Every float
+of the document is rounded to the nanosecond (:data:`DECIMALS` places)
+and then to :data:`SIGNIFICANT_DIGITS` significant digits, so the bytes
+do not hang on the last bits of a sum or of a difference of two close
+sums, which move with the order of the terms and between floating-point
+libraries.
 
 Cost model: per phase, a least-squares linear fit of exec duration vs
 task ``size_bytes`` when every span carries a size (the sim path), else
@@ -44,6 +49,23 @@ STRAGGLER_RATIO = 2.0
 
 #: Floor for cost estimates (keeps actual/estimate ratios finite).
 _EST_FLOOR = 1e-12
+
+#: Decimal places, then significant digits, kept of every float in a
+#: summary document.
+DECIMALS = 9
+SIGNIFICANT_DIGITS = 10
+
+
+def _rounded(x):
+    """``x`` with every float rounded to :data:`DECIMALS` places and
+    then to :data:`SIGNIFICANT_DIGITS` significant digits."""
+    if isinstance(x, float):
+        return float(f"{round(x, DECIMALS):.{SIGNIFICANT_DIGITS}g}")
+    if isinstance(x, dict):
+        return {k: _rounded(v) for k, v in x.items()}
+    if isinstance(x, (list, tuple)):
+        return [_rounded(v) for v in x]
+    return x
 
 
 def phase_of(task_id: Optional[str]) -> str:
@@ -210,7 +232,7 @@ def build_summary(events: Iterable[tuple], *, label: str = "run",
         "n_failed": name_counts.get("failed", 0),
         "n_requeued": name_counts.get("requeued", 0),
     }
-    return {
+    return _rounded({
         "schema": OBS_SUMMARY_SCHEMA,
         "schema_version": SCHEMA_VERSION,
         "config": {"label": label, "n_events": len(evs),
@@ -221,7 +243,7 @@ def build_summary(events: Iterable[tuple], *, label: str = "run",
         "workers": workers,
         "stragglers": stragglers,
         "shards": shards,
-    }
+    })
 
 
 def summary_from_tracer(tracer, *, label: str = "run", **kw) -> dict:

@@ -44,6 +44,15 @@ Design constraints, in order:
      (:meth:`Tracer.set_clock`), so simulated and live runs emit through
      the same API and render identically.
 
+**Stage spans** (:func:`stage`) time one layer's work inside a
+task — store decode, host packing, the device call — and open a
+``jax.profiler.TraceAnnotation`` of the same name while they run, so a
+profiler trace taken alongside shows each stage on the device trace's
+clock.  The threads backend binds each worker thread to its track
+(``w<i>``) and the ids of the task(s) it runs (:meth:`Tracer.bind`);
+a stage span takes both from that binding, so its cause is its task's
+``exec`` span.  Stage spans of one thread are siblings, never nested.
+
 The ring is bounded (``capacity`` events); overflow evicts the oldest
 event and counts it in :attr:`Tracer.dropped` — a saturated trace is
 explicitly marked, never silently wrong.
@@ -52,11 +61,12 @@ explicitly marked, never silently wrong.
 from __future__ import annotations
 
 import collections
+import threading
 import time
 from typing import Callable, Optional
 
 __all__ = ["INSTANT", "EVENT_FIELDS", "CATEGORIES", "DEFAULT_CAPACITY",
-           "Tracer"]
+           "NULL_STAGE", "Tracer", "stage"]
 
 #: Sentinel duration marking an instant event (a point, not a range).
 INSTANT = -1.0
@@ -95,6 +105,8 @@ class Tracer:
         #: hot paths; :meth:`now` is the same thing one frame slower.
         self.clock: Callable[[], float] = (clock if clock is not None
                                            else time.monotonic)
+        #: Per-thread task binding read by :func:`stage`.
+        self._bound = threading.local()
 
     def set_clock(self, clock: Callable[[], float]) -> None:
         """Rebind the time source (the sim binds its virtual clock)."""
@@ -128,6 +140,13 @@ class Tracer:
         """Range event covering ``[start, end]``."""
         self.emit(start, end - start, name, cat, track, task_id, extra)
 
+    def bind(self, track, task_ids: tuple = ()) -> None:
+        """Bind the calling thread to a worker ``track`` running
+        ``task_ids`` (one task, or every task of a batched message) for
+        the stage spans it emits; ``track=None`` unbinds it."""
+        self._bound.track = track
+        self._bound.task_ids = task_ids
+
     # -- read side ---------------------------------------------------------
 
     def __len__(self) -> int:
@@ -141,3 +160,86 @@ class Tracer:
     def clear(self) -> None:
         self._events.clear()
         self.emitted = 0
+
+
+def _annotation(name: str):
+    """An entered ``jax.profiler.TraceAnnotation(name)``, or None where
+    JAX is not installed (imported lazily: the ring needs no JAX)."""
+    try:
+        import jax.profiler as profiler
+    except ImportError:
+        return None
+    ann = profiler.TraceAnnotation(name)
+    ann.__enter__()
+    return ann
+
+
+class _Stage:
+    """One open stage span (see :func:`stage`)."""
+
+    __slots__ = ("_tr", "_name", "_cat", "_track", "_ann", "_t0", "extra")
+
+    def __init__(self, tracer: Tracer, name: str, cat: str, track):
+        self._tr = tracer
+        self._name = name
+        self._cat = cat
+        self._track = track
+        self.extra: Optional[dict] = None
+
+    def __enter__(self) -> "_Stage":
+        self._ann = _annotation(self._name)
+        self._t0 = self._tr.clock()
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        tr = self._tr
+        t1 = tr.clock()
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
+        extra = self.extra
+        track = getattr(tr._bound, "track", None)
+        if track is None:
+            track = (self._track if self._track is not None
+                     else threading.current_thread().name)
+            task_id = None
+        else:
+            ids = tr._bound.task_ids
+            task_id = ids[0] if ids else None
+            if len(ids) > 1:
+                extra = dict(extra or {}, tasks=ids)
+        tr.emit(self._t0, t1 - self._t0, self._name, self._cat, track,
+                task_id, extra)
+        return False
+
+
+class _NullStage:
+    """What :func:`stage` returns without a tracer: does nothing."""
+
+    __slots__ = ()
+
+    def __enter__(self) -> "_NullStage":
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        return False
+
+    extra = property(lambda self: None, lambda self, value: None)
+
+
+NULL_STAGE = _NullStage()
+
+
+def stage(tracer: Optional[Tracer], name: str, cat: str, track=None):
+    """Context manager: one stage span in ``tracer``'s ring, held open
+    as a ``jax.profiler.TraceAnnotation(name)`` while it runs; without
+    a tracer, :data:`NULL_STAGE` (no span, no annotation).
+
+    On a bound thread (:meth:`Tracer.bind`) the span carries the bound
+    track and the first bound task id, and ``extra["tasks"]`` lists
+    every bound id when there are several.  On an unbound thread it
+    carries ``track`` (default: the thread's name) and no task id.
+    Counters go in a dict set as ``.extra`` on the returned object
+    before the block ends."""
+    if tracer is None:
+        return NULL_STAGE
+    return _Stage(tracer, name, cat, track)

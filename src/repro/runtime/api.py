@@ -134,7 +134,14 @@ def run_job(tasks: Sequence[Task],
     ``tracer`` attaches a :class:`repro.obs.Tracer`: task lifecycle
     instants and exec spans are emitted on every backend (the sim binds
     its virtual clock, so traced sim runs stay bit-reproducible and
-    tracing never changes a dispatch decision).
+    tracing never changes a dispatch decision).  Live exec spans are
+    each task's execution as its worker timed it, with the worker
+    thread's CPU seconds in ``extra["worker_cpu"]``.  On the threads
+    backend a worker function with an ``attach_tracer(tracer)`` method
+    (returning the tracer it had) gets the tracer for the job, so its
+    stage spans (:func:`repro.obs.stage`) land in the same ring under
+    the worker's track and task id; the processes backend's workers
+    cannot share the ring and emit no stage spans.
 
     ``speculative`` re-issues the longest-in-flight task to idle
     workers once the queue drains (at most ``speculation_max_copies``
@@ -262,15 +269,24 @@ def run_job(tasks: Sequence[Task],
     kwargs: dict[str, Any] = {}
     if backend == "processes" and mp_context is not None:
         kwargs["mp_context"] = mp_context
+    attach = None
+    if backend == "threads" and tracer is not None:
+        kwargs["tracer"] = tracer
+        attach = getattr(fn, "attach_tracer", None)
     transport = transport_cls(
         n_workers, fn, batch_fn=batch_fn, poll_interval=poll_interval,
         heartbeat_interval=heartbeat, worker_fail_after=worker_fail_after,
         worker_slow_factor=worker_slow_factor,
         **kwargs)
-    return drive(core, transport,
-                 poll_interval=poll_interval,
-                 failure_timeout=failure_timeout,
-                 on_checkpoint=on_checkpoint,
-                 checkpoint_interval_s=checkpoint_interval_s,
-                 raise_on_failure=raise_on_failure,
-                 backend=backend)
+    prev = attach(tracer) if attach is not None else None
+    try:
+        return drive(core, transport,
+                     poll_interval=poll_interval,
+                     failure_timeout=failure_timeout,
+                     on_checkpoint=on_checkpoint,
+                     checkpoint_interval_s=checkpoint_interval_s,
+                     raise_on_failure=raise_on_failure,
+                     backend=backend)
+    finally:
+        if attach is not None:
+            attach(prev)

@@ -827,10 +827,6 @@ def drive(core: SchedulerCore, transport, *,
     stats = {wid: WorkerStats(wid) for wid in worker_ids}
     results: dict[str, Any] = {}
     tracer = getattr(core, "tracer", None)
-    # Per-worker end of the last emitted exec span: live exec spans are
-    # reconstructed from DONE-reported busy windows and clamped to never
-    # overlap within a worker's timeline.
-    exec_end: dict[Any, float] = {}
     # Elastic fleet: the controller rides on the core (run_job attaches
     # it) and only engages on transports that can actually scale.
     fleet = getattr(core, "fleet", None)
@@ -942,20 +938,14 @@ def drive(core: SchedulerCore, transport, *,
                         s.first_task_at = now - msg.busy_seconds
                     s.last_done_at = now
                     if tracer is not None and fresh_ids:
-                        # The batch's reported busy window, split evenly
-                        # across its tasks (the worker does not report
-                        # per-task boundaries), clamped so spans never
-                        # overlap within this worker's row.
-                        start = max(now - msg.busy_seconds,
-                                    exec_end.get(msg.sender, t_start))
-                        start = min(start, now)
-                        step = (now - start) / len(fresh_ids)
-                        raw = tracer.raw
-                        for i, tid in enumerate(fresh_ids):
-                            raw((start + i * step, step, "exec", "task",
-                                 msg.sender, tid, None))
-                        tracer.emitted += len(fresh_ids)
-                        exec_end[msg.sender] = now
+                        # Each task's execution as the worker timed it,
+                        # with the worker thread's CPU seconds.
+                        for tid, (start, secs, cpu) in zip(
+                                msg.task_ids, msg.task_spans):
+                            if tid in fresh:
+                                tracer.emit(start, secs, "exec", "task",
+                                            msg.sender, tid,
+                                            {"worker_cpu": cpu})
                     if msg.sender not in core.dead:
                         send(msg.sender)
                 elif msg.kind is MessageKind.FAILED:

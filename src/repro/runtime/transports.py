@@ -13,6 +13,13 @@ One worker loop serves both live backends — it only needs a blocking
 ``fail_after`` kills a worker after N completed tasks (fault-injection
 hook for tests): the worker returns without sending DONE, exactly like a
 node death mid-batch.
+
+Each DONE carries, per task, the start and length of its execution and
+the worker thread's CPU seconds (``time.thread_time``): the manager's
+``exec`` spans.  Given a :class:`repro.obs.Tracer` (threads backend
+only: worker processes cannot share the ring), a worker binds its
+thread to its id and the task ids it runs, so the stage spans of the
+worker function carry both.
 """
 
 from __future__ import annotations
@@ -39,7 +46,8 @@ def worker_loop(worker_id: str, inbox, to_manager: Callable[[Message], None],
                 poll_interval: float = DEFAULT_POLL_INTERVAL_S,
                 heartbeat_interval: Optional[float] = None,
                 fail_after: Optional[int] = None,
-                slow_factor: Optional[float] = None) -> None:
+                slow_factor: Optional[float] = None,
+                tracer: Optional[Any] = None) -> None:
     """A worker process: poll for ASSIGN, run, report DONE, repeat.
 
     "While idle, the workers wait 0.3 seconds prior between checking if
@@ -73,7 +81,7 @@ def worker_loop(worker_id: str, inbox, to_manager: Callable[[Message], None],
                          daemon=True).start()
     try:
         _worker_recv_loop(worker_id, inbox, to_manager, fn, batch_fn,
-                          poll_interval, fail_after, slow_factor)
+                          poll_interval, fail_after, slow_factor, tracer)
     finally:
         if stop_heartbeats is not None:
             stop_heartbeats.set()
@@ -81,7 +89,7 @@ def worker_loop(worker_id: str, inbox, to_manager: Callable[[Message], None],
 
 def _worker_recv_loop(worker_id, inbox, to_manager, fn, batch_fn,
                       poll_interval, fail_after,
-                      slow_factor=None) -> None:
+                      slow_factor=None, tracer=None) -> None:
     completed = 0
     drag = (slow_factor - 1.0) if slow_factor and slow_factor > 1.0 else 0.0
     while True:
@@ -95,29 +103,44 @@ def _worker_recv_loop(worker_id, inbox, to_manager, fn, batch_fn,
         tasks = list(msg.tasks)
         done_ids: list[str] = []
         res: list[Any] = []
+        spans: list[tuple[float, float, float]] = []
         t0 = time.monotonic()
         if batch_fn is not None and len(tasks) > 1:
             if fail_after is not None and completed + len(tasks) > fail_after:
                 return  # simulate node death mid-batch: no DONE sent
+            ids = tuple(t.task_id for t in tasks)
+            if tracer is not None:
+                tracer.bind(worker_id, ids)
+            c0 = time.thread_time()
             try:
                 out = batch_fn(tasks)
             except Exception as e:  # whole batch fails together
                 to_manager(Message(
                     MessageKind.FAILED, sender=worker_id,
-                    task_ids=tuple(t.task_id for t in tasks), error=repr(e)))
+                    task_ids=ids, error=repr(e)))
                 continue
+            finally:
+                if tracer is not None:
+                    tracer.bind(None)
             if drag:
                 time.sleep(drag * (time.monotonic() - t0))
-            for t in tasks:
+            # One call for every task: its window and CPU, split evenly.
+            step = (time.monotonic() - t0) / len(tasks)
+            cpu = (time.thread_time() - c0) / len(tasks)
+            for i, t in enumerate(tasks):
                 done_ids.append(t.task_id)
                 res.append(out.get(t.task_id) if isinstance(out, dict)
                            else out)
+                spans.append((t0 + i * step, step, cpu))
             completed += len(tasks)
         else:
             for task in tasks:
                 if fail_after is not None and completed >= fail_after:
                     return  # simulate node death mid-batch: no DONE sent
+                if tracer is not None:
+                    tracer.bind(worker_id, (task.task_id,))
                 t_task = time.monotonic()
+                c_task = time.thread_time()
                 try:
                     r = fn(task)
                 except Exception as e:  # report, don't die
@@ -125,8 +148,13 @@ def _worker_recv_loop(worker_id, inbox, to_manager, fn, batch_fn,
                         MessageKind.FAILED, sender=worker_id,
                         task_ids=(task.task_id,), error=repr(e)))
                     continue
+                finally:
+                    if tracer is not None:
+                        tracer.bind(None)
                 if drag:
                     time.sleep(drag * (time.monotonic() - t_task))
+                spans.append((t_task, time.monotonic() - t_task,
+                              time.thread_time() - c_task))
                 done_ids.append(task.task_id)
                 res.append(r)
                 completed += 1
@@ -141,7 +169,7 @@ def _worker_recv_loop(worker_id, inbox, to_manager, fn, batch_fn,
                 MessageKind.DONE, sender=worker_id,
                 task_ids=tuple(done_ids), results=tuple(res),
                 busy_seconds=time.monotonic() - t0,
-                wait_seconds=wait_s))
+                wait_seconds=wait_s, task_spans=tuple(spans)))
 
 
 class Transport(abc.ABC):
@@ -205,11 +233,13 @@ class ThreadTransport(_LiveTransport):
     The only elastic live transport: :meth:`add_worker` spawns a fresh
     worker thread mid-run and :meth:`retire_worker` shuts one down, which
     is what the :class:`~repro.runtime.fleet.FleetController` drives
-    through the live ``drive()`` loop.
+    through the live ``drive()`` loop.  ``tracer`` binds each worker
+    thread's stage spans to its id and tasks.
     """
 
-    def __init__(self, *args, **kwargs):
+    def __init__(self, *args, tracer: Optional[Any] = None, **kwargs):
         super().__init__(*args, **kwargs)
+        self._tracer = tracer
         self._inboxes: dict[str, "queue.Queue[Message]"] = {
             wid: queue.Queue() for wid in self.worker_ids}
         self._mgr_inbox: "queue.Queue[Message]" = queue.Queue()
@@ -222,7 +252,7 @@ class ThreadTransport(_LiveTransport):
             target=worker_loop, name=f"worker-{wid}", daemon=True,
             args=(wid, self._inboxes[wid], self._mgr_inbox.put,
                   self._fn),
-            kwargs=self._worker_kwargs(wid))
+            kwargs=dict(self._worker_kwargs(wid), tracer=self._tracer))
         th.start()
         self._threads.append(th)
         self._by_id[wid] = th

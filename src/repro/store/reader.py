@@ -39,6 +39,7 @@ from typing import Iterator, Optional, Sequence
 
 import numpy as np
 
+from repro.obs.tracer import stage
 from repro.store import codec
 from repro.store.format import ShardRecord, StoreManifest, TrackRecord
 
@@ -121,7 +122,10 @@ class TrackStore:
         self.manifest = manifest or StoreManifest.load(root)
         self.prefetch = prefetch
         #: Optional :class:`repro.obs.Tracer`: shard decodes become
-        #: ``store``-category spans (track = shard id), consumer blocking
+        #: ``store_decode`` stage spans (:func:`repro.obs.stage`: under
+        #: the worker's track and task on a runtime worker thread, else
+        #: track = shard id; ``extra`` holds the shard's ``bytes``, its
+        #: id and the ``obs`` decoded), consumer blocking
         #: becomes ``store_wait`` spans, and prefetch handoffs become
         #: instants.  Spans use the *tracer's* clock — not ``clock`` —
         #: so they share one timeline with scheduler/serving events.
@@ -238,35 +242,35 @@ class TrackStore:
         rec = plan.shard
         t0 = self._clock()
         tr = self.tracer
-        tt0 = tr.now() if tr is not None else 0.0
-        path = os.path.join(self.root, rec.filename)
-        cols, meta = codec.read_shard(path)
-        offsets = cols["offsets"]
-        values = meta.get("icao_values", [])
-        items: list[tuple[dict, list[slice]]] = []
-        track_ids: list[str] = []
-        value_arr = (np.asarray(values) if values
-                     else np.zeros(0, dtype="U1"))
-        for t in plan.tracks:
-            lo, hi = int(offsets[t.row]), int(offsets[t.row + 1])
-            codes = cols["icao_codes"][lo:hi]
-            names = (value_arr[codes] if len(codes)
-                     else np.zeros(0, dtype="U1"))
-            obs = {
-                "time": cols["time"][lo:hi],
-                "lat": cols["lat"][lo:hi],
-                "lon": cols["lon"][lo:hi],
-                "alt": cols["alt"][lo:hi],
-                "icao24": names,
-            }
-            items.append((obs, split_segments(obs["time"])))
-            track_ids.append(t.track_id)
+        with stage(tr, "store_decode", "store", rec.shard_id) as st:
+            path = os.path.join(self.root, rec.filename)
+            cols, meta = codec.read_shard(path)
+            offsets = cols["offsets"]
+            values = meta.get("icao_values", [])
+            items: list[tuple[dict, list[slice]]] = []
+            track_ids: list[str] = []
+            value_arr = (np.asarray(values) if values
+                         else np.zeros(0, dtype="U1"))
+            for t in plan.tracks:
+                lo, hi = int(offsets[t.row]), int(offsets[t.row + 1])
+                codes = cols["icao_codes"][lo:hi]
+                names = (value_arr[codes] if len(codes)
+                         else np.zeros(0, dtype="U1"))
+                obs = {
+                    "time": cols["time"][lo:hi],
+                    "lat": cols["lat"][lo:hi],
+                    "lon": cols["lon"][lo:hi],
+                    "alt": cols["alt"][lo:hi],
+                    "icao24": names,
+                }
+                items.append((obs, split_segments(obs["time"])))
+                track_ids.append(t.track_id)
+            if tr is not None:
+                st.extra = {"bytes": rec.size_bytes, "shard": rec.shard_id,
+                            "obs": sum(len(o["time"]) for o, _ in items)}
         self.stats["shards_read"] += 1
         self.stats["bytes_read"] += rec.size_bytes
         self.stats["decode_s"] += self._clock() - t0
-        if tr is not None:
-            tr.emit(tt0, tr.now() - tt0, "store_decode", "store",
-                    rec.shard_id, extra=rec.size_bytes)
         return ShardBatch(shard_id=rec.shard_id, track_ids=track_ids,
                           items=items)
 

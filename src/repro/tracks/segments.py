@@ -40,6 +40,7 @@ from repro.geometry.aerodromes import Aerodrome
 from repro.geometry.dem import SyntheticGlobeDEM
 from repro.geometry.queries import RADIUS_DEG
 from repro.kernels import ops
+from repro.obs.tracer import stage
 
 MIN_OBS_PER_SEGMENT = 10       # paper: remove segments with <10 observations
 SEGMENT_GAP_S = 120.0          # new segment after a 2-minute gap
@@ -196,6 +197,9 @@ class SegmentProcessor:
         self.backend = backend
         self.pipeline = pipeline
         self._stores: dict = {}          # store root -> TrackStore
+        #: Optional repro.obs.Tracer (attach_tracer): stage spans of the
+        #: fused path and of its stores' decodes.
+        self.tracer = None
         self._dem_f32 = self.dem.elevation_m.astype(np.float32)
         self._dem_grid = (self.dem.lat_min, self.dem.lat_max,
                           self.dem.lon_min, self.dem.lon_max,
@@ -205,6 +209,15 @@ class SegmentProcessor:
             self._aero_lat = np.array([a.lat for a in self.aerodromes])
             self._aero_lon = np.array([a.lon for a in self.aerodromes])
             self._aero_cls = [a.airspace_class for a in self.aerodromes]
+
+    def attach_tracer(self, tracer):
+        """Emit stage spans into ``tracer`` (None: stop); returns the
+        tracer attached before.  ``run_job`` calls this for a traced
+        threads job."""
+        prev, self.tracer = self.tracer, tracer
+        for store in self._stores.values():
+            store.tracer = tracer
+        return prev
 
     # -- io -------------------------------------------------------------
 
@@ -232,7 +245,8 @@ class SegmentProcessor:
         store = self._stores.get(root)
         if store is None:
             from repro.store.reader import TrackStore
-            store = self._stores[root] = TrackStore(root)
+            store = self._stores[root] = TrackStore(root,
+                                                    tracer=self.tracer)
         return store
 
     def _store_read(self, root: str, fn):
@@ -403,8 +417,19 @@ class SegmentProcessor:
         """Bucketed ragged batching: flatten every archive's segments,
         bin them by power-of-two width, run ONE fused device call per
         bucket (cached compilation per shape), then reassemble rows into
-        per-archive planes."""
-        records = self._records(items)
+        per-archive planes.
+
+        With a tracer attached, each step is a stage span: the
+        segmentation into records (``segments.records``), per bucket the
+        host packing (``segments.pack``) and the device call up to its
+        fetch (``segments.device``, with the bucket's valid and
+        allocated points), and the reassembly (``segments.reassemble``).
+        """
+        tr = self.tracer
+        with stage(tr, "segments.records", "task") as st:
+            records = self._records(items)
+            if tr is not None:
+                st.extra = {"segments": len(records)}
         # Bucket key includes the fallback flag: a segment's compiled
         # graph variant must be a function of the segment alone, or
         # per-archive outputs could drift an ulp depending on which
@@ -414,47 +439,73 @@ class SegmentProcessor:
         for gi, rec in enumerate(records):
             buckets.setdefault((rec.width, rec.may_span), []).append(gi)
 
-        planes: dict[int, dict[str, np.ndarray]] = {}   # gi -> field rows
+        fetched: list[tuple[list[int], dict]] = []  # (rows, planes)
         allocated = 0
         for width, may_span in sorted(buckets):
             idxs = buckets[(width, may_span)]
             bk = len(idxs)
             bp = _round_rows(bk)
             allocated += bp * width
-            # The knot axis gets its own (smaller) 128-multiple width:
-            # raw observations are ~5-8x sparser than the 1 Hz output
-            # grid, so tying knots to the output bucket would waste most
-            # of the interp kernel's mask matmul.
-            kn = -(-max(records[gi].n for gi in idxs) // 128) * 128
-            t_in = np.zeros((bp, kn), np.float32)
-            v_in = np.zeros((bp, 3, kn), np.float32)
-            count_in = np.full((bp,), 2, np.int32)
-            t_out = np.zeros((bp, width), np.float32)
-            count_out = np.ones((bp,), np.int32)
-            # Benign padding rows: strictly increasing knots, zero values.
-            t_in[bk:] = np.arange(kn, dtype=np.float32)[None, :]
-            for r, gi in enumerate(idxs):
-                rec = records[gi]
-                n, m = rec.n, rec.m
-                t0 = rec.t[0]
-                t_in[r, :n] = rec.t - t0
-                t_in[r, n:] = (rec.t[-1] - t0) + np.arange(1, kn - n + 1)
-                v_in[r, 0, :n] = rec.lat
-                v_in[r, 1, :n] = rec.lon
-                v_in[r, 2, :n] = rec.alt
-                # hold last value through padding (keeps interp defined)
-                v_in[r, :, n:] = v_in[r, :, n - 1:n]
-                count_in[r] = n
-                t_out[r, :m] = np.arange(m) * RESAMPLE_DT_S
-                t_out[r, m:] = t_out[r, m - 1]
-                count_out[r] = m
-            out = ops.process_segments(
-                self._dem_f32, t_in, v_in, count_in, t_out, count_out,
-                grid=self._dem_grid, dt=RESAMPLE_DT_S,
-                backend=self.backend, agl_oracle=may_span)
-            # ONE device->host fetch per bucket — the pipeline's only
-            # downward transfer.
-            host = {k: np.asarray(v) for k, v in out.items()}
+            with stage(tr, "segments.pack", "task") as st:
+                # The knot axis gets its own (smaller) 128-multiple
+                # width: raw observations are ~5-8x sparser than the 1 Hz
+                # output grid, so tying knots to the output bucket would
+                # waste most of the interp kernel's mask matmul.
+                kn = -(-max(records[gi].n for gi in idxs) // 128) * 128
+                t_in = np.zeros((bp, kn), np.float32)
+                v_in = np.zeros((bp, 3, kn), np.float32)
+                count_in = np.full((bp,), 2, np.int32)
+                t_out = np.zeros((bp, width), np.float32)
+                count_out = np.ones((bp,), np.int32)
+                # Benign padding rows: strictly increasing knots, zero
+                # values.
+                t_in[bk:] = np.arange(kn, dtype=np.float32)[None, :]
+                for r, gi in enumerate(idxs):
+                    rec = records[gi]
+                    n, m = rec.n, rec.m
+                    t0 = rec.t[0]
+                    t_in[r, :n] = rec.t - t0
+                    t_in[r, n:] = (rec.t[-1] - t0) + np.arange(1, kn - n + 1)
+                    v_in[r, 0, :n] = rec.lat
+                    v_in[r, 1, :n] = rec.lon
+                    v_in[r, 2, :n] = rec.alt
+                    # hold last value through padding (keeps interp
+                    # defined)
+                    v_in[r, :, n:] = v_in[r, :, n - 1:n]
+                    count_in[r] = n
+                    t_out[r, :m] = np.arange(m) * RESAMPLE_DT_S
+                    t_out[r, m:] = t_out[r, m - 1]
+                    count_out[r] = m
+                if tr is not None:
+                    st.extra = {"rows": bk, "width": width}
+            with stage(tr, "segments.device", "task") as st:
+                out = ops.process_segments(
+                    self._dem_f32, t_in, v_in, count_in, t_out, count_out,
+                    grid=self._dem_grid, dt=RESAMPLE_DT_S,
+                    backend=self.backend, agl_oracle=may_span)
+                # ONE device->host fetch per bucket — the pipeline's only
+                # downward transfer.
+                fetched.append((idxs, {k: np.asarray(v)
+                                       for k, v in out.items()}))
+                del out         # release the device buffers in this span
+                if tr is not None:
+                    st.extra = {"valid": int(count_out[:bk].sum()),
+                                "allocated": bp * width}
+
+        with stage(tr, "segments.reassemble", "task") as st:
+            out_list = self._reassemble(items, records, buckets, fetched,
+                                        allocated)
+            del fetched     # the bucket planes are freed in this span too
+            if tr is not None:
+                st.extra = {"segments": len(records)}
+        return out_list
+
+    def _reassemble(self, items, records, buckets, fetched, allocated
+                    ) -> list[ProcessedSegments]:
+        """Fetched bucket planes -> per-archive ProcessedSegments, with
+        airspace classes and :attr:`last_stats`."""
+        planes: dict[int, dict[str, np.ndarray]] = {}   # gi -> field rows
+        for idxs, host in fetched:
             for r, gi in enumerate(idxs):
                 planes[gi] = {k: v[r] for k, v in host.items()}
 

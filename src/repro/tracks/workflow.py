@@ -70,6 +70,7 @@ from repro.geometry.gridhash import GridSpec, cell_cost, cell_id
 from repro.kernels.encounter_screen import (
     ScreenConfig, bin_screen_rows, dedup_candidates, rows_from_track,
     screen_cells)
+from repro.obs.tracer import stage
 from repro.runtime import (
     EdgeEmitter, ManagerCheckpoint, RunResult, StreamingDAG, run_dag,
     run_job)
@@ -232,9 +233,12 @@ def _screen_rows_for_uri(proc: SegmentProcessor, uri: str) -> list:
     items = proc._store_items(uri)
     procd = proc._process_triples(items)
     rows = []
-    for tid, obs, segs in items:
-        if segs:
-            rows.extend(rows_from_track(tid, obs, segs, procd[tid]))
+    with stage(proc.tracer, "screen.plan.rows", "task") as st:
+        for tid, obs, segs in items:
+            if segs:
+                rows.extend(rows_from_track(tid, obs, segs, procd[tid]))
+        if proc.tracer is not None:
+            st.extra = {"rows": len(rows)}
     return rows
 
 
@@ -250,22 +254,36 @@ class ScreenWorker:
     ``new != all`` (a streaming-DAG generation) only pairs touching a
     new row are emitted.  Picklable for the processes backend; the
     SegmentProcessor is built lazily per process.
+
+    With a ``tracer`` (a pickled copy drops it: worker processes emit no
+    spans) each member read is a ``store_decode`` span and its
+    re-derivation ``segments.*`` spans, then per track ``screen.rows``
+    (``rows_from_track``) and per cell ``screen.kernel``.
     """
 
     def __init__(self, store_dir: str, *, h_thresh_m: float,
                  v_thresh_m: float, backend: str = "pallas",
-                 pipeline: str = "fused"):
+                 pipeline: str = "fused", tracer=None):
         self.store_dir = store_dir
         self.h_thresh_m = h_thresh_m
         self.v_thresh_m = v_thresh_m
         self.backend = backend
         self.pipeline = pipeline
+        self.tracer = tracer
         self._proc: Optional[SegmentProcessor] = None
 
     def __getstate__(self) -> dict:
         state = dict(self.__dict__)
         state["_proc"] = None
+        state["tracer"] = None
         return state
+
+    def attach_tracer(self, tracer):
+        """Emit stage spans into ``tracer``; returns the one before."""
+        prev, self.tracer = self.tracer, tracer
+        if self._proc is not None:
+            self._proc.attach_tracer(tracer)
+        return prev
 
     def _processor(self) -> SegmentProcessor:
         if self._proc is None:
@@ -273,6 +291,7 @@ class ScreenWorker:
                 dem=SyntheticGlobeDEM(),
                 aerodromes=synthetic_aerodromes(n=64),
                 backend=self.backend, pipeline=self.pipeline)
+            self._proc.attach_tracer(self.tracer)
         return self._proc
 
     def _config(self) -> ScreenConfig:
@@ -284,6 +303,7 @@ class ScreenWorker:
         wanted = set(doc["all"])
         tracks = sorted({rid.rsplit("#", 1)[0] for rid in wanted})
         proc = self._processor()
+        tr = self.tracer
         rows = []
         for tid in tracks:
             uri = make_store_uri(self.store_dir, track=tid)
@@ -292,12 +312,20 @@ class ScreenWorker:
             if not segs:
                 continue
             ps = proc.process_arrays(obs, segs)
-            rows.extend(r for r in rows_from_track(tid, obs, segs, ps)
-                        if r.row_id in wanted)
+            with stage(tr, "screen.rows", "task") as st:
+                n = len(rows)
+                rows.extend(r for r in rows_from_track(tid, obs, segs, ps)
+                            if r.row_id in wanted)
+                if tr is not None:
+                    st.extra = {"rows": len(rows) - n}
         new = set(doc["new"])
-        cands, stats = screen_cells(
-            {doc["cell"]: rows}, config=self._config(),
-            new_ids=None if new >= wanted else {doc["cell"]: new})
+        with stage(tr, "screen.kernel", "task") as st:
+            cands, stats = screen_cells(
+                {doc["cell"]: rows}, config=self._config(),
+                new_ids=None if new >= wanted else {doc["cell"]: new})
+            if tr is not None:
+                st.extra = {"pairs": stats["pairs_screened"],
+                            "candidates": len(cands)}
         return {"candidates": cands, "stats": stats}
 
 
@@ -569,21 +597,29 @@ class TrackWorkflow:
         return ScreenWorker(self.store_dir,
                             h_thresh_m=self.screen_config.h_thresh_m,
                             v_thresh_m=self.screen_config.v_thresh_m,
-                            backend=self.backend, pipeline=self.pipeline)
+                            backend=self.backend, pipeline=self.pipeline,
+                            tracer=self.tracer)
 
     def _screen_tasks_full(self) -> list[Task]:
         """One task per multi-row cell over the *finished* store — the
-        barrier screen plan (``new == all``: every pair screened)."""
+        barrier screen plan (``new == all``: every pair screened).
+        Traced, it emits the shards' ``store_decode`` and ``segments.*``
+        spans, ``screen.plan.rows`` and ``screen.plan.bin``, on the
+        calling thread's track and with no task id."""
         proc = SegmentProcessor(
             dem=SyntheticGlobeDEM(),
             aerodromes=synthetic_aerodromes(n=64),
             backend=self.backend, pipeline=self.pipeline)
+        proc.attach_tracer(self.tracer)
         rows = []
         for t in segment_tasks_from_store(self.store_dir,
                                           granularity="shard"):
             rows.extend(_screen_rows_for_uri(proc, t.payload))
-        bins = bin_screen_rows(rows, grid=self.screen_grid,
-                               config=self.screen_config)
+        with stage(self.tracer, "screen.plan.bin", "task") as st:
+            bins = bin_screen_rows(rows, grid=self.screen_grid,
+                                   config=self.screen_config)
+            if self.tracer is not None:
+                st.extra = {"rows": len(rows), "cells": len(bins)}
         tasks = []
         for key in sorted(bins):
             ids = sorted(bins[key])

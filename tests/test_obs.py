@@ -193,7 +193,7 @@ def test_live_threads_ledger_invariants():
                   tasks_per_message=2, tracer=tr)
     assert len(res.completed_ids) == 12
     _ledger_invariants(tr.events, res.completed_ids)
-    # Live exec spans are drive-side reconstructions on the wall clock.
+    # Live exec spans are the workers' own timings on the wall clock.
     assert all(e[1] >= 0.0 for e in tr.events if e[2] == "exec")
 
 
@@ -248,8 +248,12 @@ def test_store_reader_spans_zero_sleep(served_store):
     assert len(decodes) == n
     assert {e[4] for e in decodes} \
         == {s.shard_id for s in served_store["manifest"].shards}
-    # extra carries the shard payload size for cost attribution.
-    assert all(isinstance(e[6], int) and e[6] > 0 for e in decodes)
+    # extra carries the shard payload size for cost attribution, and
+    # the observations decoded.
+    assert all(isinstance(e[6]["bytes"], int) and e[6]["bytes"] > 0
+               for e in decodes)
+    assert sum(e[6]["obs"] for e in decodes) \
+        == served_store["manifest"].n_points
     assert all(e[1] > 0.0 for e in decodes)
     # The prefetch thread emitted handoff instants through the same
     # ring (GIL-atomic appends), and the consumer measured its waits.
