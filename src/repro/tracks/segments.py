@@ -416,14 +416,17 @@ class SegmentProcessor:
                             ) -> list[ProcessedSegments]:
         """Bucketed ragged batching: flatten every archive's segments,
         bin them by power-of-two width, run ONE fused device call per
-        bucket (cached compilation per shape), then reassemble rows into
-        per-archive planes.
+        bucket (cached compilation per shape), then reassemble the
+        bucket planes into per-archive planes with whole-bucket gathers
+        into one array per plane and archive width (:meth:`_reassemble`).
 
         With a tracer attached, each step is a stage span: the
         segmentation into records (``segments.records``), per bucket the
         host packing (``segments.pack``) and the device call up to its
         fetch (``segments.device``, with the bucket's valid and
-        allocated points), and the reassembly (``segments.reassemble``).
+        allocated points), and the reassembly (``segments.reassemble``,
+        with the ``segments`` reassembled and the ``gathers`` made: one
+        per pair of source bucket and width group).
         """
         tr = self.tracer
         with stage(tr, "segments.records", "task") as st:
@@ -493,28 +496,82 @@ class SegmentProcessor:
                                 "allocated": bp * width}
 
         with stage(tr, "segments.reassemble", "task") as st:
-            out_list = self._reassemble(items, records, buckets, fetched,
-                                        allocated)
+            out_list, gathers = self._reassemble(items, records, buckets,
+                                                 fetched, allocated)
             del fetched     # the bucket planes are freed in this span too
             if tr is not None:
-                st.extra = {"segments": len(records)}
+                st.extra = {"segments": len(records), "gathers": gathers}
         return out_list
 
     def _reassemble(self, items, records, buckets, fetched, allocated
-                    ) -> list[ProcessedSegments]:
+                    ) -> tuple[list[ProcessedSegments], int]:
         """Fetched bucket planes -> per-archive ProcessedSegments, with
-        airspace classes and :attr:`last_stats`."""
-        planes: dict[int, dict[str, np.ndarray]] = {}   # gi -> field rows
+        airspace classes and :attr:`last_stats`; also returns the number
+        of gather-assignments made.
+
+        The numpy work scales with buckets, not rows.  Archives are
+        grouped by ``wmax``, the widest bucket among their segments (at
+        most ``len(BUCKET_SIZES)`` groups).  Each group gets one zeroed
+        ``(plane, rows, wmax)`` block, filled by one gather-assignment
+        per plane and (source bucket, group) pair; columns past a
+        segment's own bucket width stay zero.  Each archive's planes are
+        its contiguous row slice of its group's block: C-contiguous,
+        writable, and sharing no row with another archive."""
+        # Place each archive in its group, in archive order: its rows are
+        # contiguous there.  Index bookkeeping is plain Python; numpy
+        # runs once per plane and (bucket, group) pair.
+        widths = [rec.width for rec in records]
+        ms = [rec.m for rec in records]
+        placed: list = []   # per archive: (first record, n, wmax, first row)
+        group_m: dict[int, list[int]] = {}   # wmax -> its rows' counts
+        dst_of: list[tuple[int, int]] = []   # per record: (wmax, row)
+        s = 0
+        for _, segs in items:
+            n = len(segs)
+            if not n:
+                placed.append(None)
+                continue
+            w = max(widths[s:s + n])
+            m = group_m.setdefault(w, [])
+            placed.append((s, n, w, len(m)))
+            dst_of.extend((w, r) for r in range(len(m), len(m) + n))
+            m.extend(ms[s:s + n])
+            s += n
+        # One allocation per group, not one per plane.
+        groups = {w: np.zeros((len(_PLANE_ATTRS), len(m), w), np.float32)
+                  for w, m in group_m.items()}
+
+        gathers = 0
+        order: list[int] = []               # records in bucket order
+        lat0, lon0 = [], []
         for idxs, host in fetched:
+            order.extend(idxs)
+            lat0.append(host["lat"][:len(idxs), 0])
+            lon0.append(host["lon"][:len(idxs), 0])
+            pairs: dict[int, tuple[list[int], list[int]]] = {}
             for r, gi in enumerate(idxs):
-                planes[gi] = {k: v[r] for k, v in host.items()}
+                w, row = dst_of[gi]
+                if w not in pairs:
+                    pairs[w] = ([], [])
+                pairs[w][0].append(r)
+                pairs[w][1].append(row)
+            width = host["lat"].shape[1]
+            for w, (src, dst) in pairs.items():
+                src, dst = _row_index(src), _row_index(dst)
+                for p, (plane, _) in enumerate(_PLANE_ATTRS):
+                    groups[w][p, dst, :width] = host[plane][src]
+                gathers += 1
 
-        # Airspace class for every segment in one vectorized query.
-        lat0 = np.array([planes[gi]["lat"][0] for gi in range(len(records))])
-        lon0 = np.array([planes[gi]["lon"][0] for gi in range(len(records))])
-        airspace = self._airspace_classes(lat0, lon0)
+        # Airspace class for every segment in one vectorized query over
+        # the start points, then back into record order.
+        airspace: list = [None] * len(records)
+        if records:
+            for gi, cls in zip(order, self._airspace_classes(
+                    np.concatenate(lat0), np.concatenate(lon0))):
+                airspace[gi] = cls
+        names = [rec.name for rec in records]
 
-        valid = sum(rec.m for rec in records)
+        valid = sum(ms)
         bucket_rows: dict[int, int] = {}
         for (width, _), ix in buckets.items():
             bucket_rows[int(width)] = bucket_rows.get(int(width), 0) \
@@ -523,27 +580,20 @@ class SegmentProcessor:
             "fused", self.backend, len(records), int(valid),
             int(allocated), bucket_rows, len(buckets))
 
+        counts = {w: np.array(m, np.int32) for w, m in group_m.items()}
         out_list: list[ProcessedSegments] = []
-        gi = 0
-        for ai, (_, segs) in enumerate(items):
-            rows = list(range(gi, gi + len(segs)))
-            gi += len(segs)
-            if not rows:
+        for place in placed:
+            if place is None:
                 out_list.append(_empty())
                 continue
-            wmax = max(records[r].width for r in rows)
-            fields = {attr: np.zeros((len(rows), wmax), np.float32)
-                      for _, attr in _PLANE_ATTRS}
-            for b, r in enumerate(rows):
-                w = records[r].width
-                for plane, attr in _PLANE_ATTRS:
-                    fields[attr][b, :w] = planes[r][plane]
+            s, n, w, r = place
+            own = slice(r, r + n)
             out_list.append(ProcessedSegments(
-                icao24=[records[r].name for r in rows],
-                count=np.array([records[r].m for r in rows], np.int32),
-                airspace=[airspace[r] for r in rows],
-                **fields))
-        return out_list
+                icao24=names[s:s + n], count=counts[w][own],
+                airspace=airspace[s:s + n],
+                **{attr: groups[w][p, own]
+                   for p, (_, attr) in enumerate(_PLANE_ATTRS)}))
+        return out_list, gathers
 
     # -- unfused baseline (three launches + host hops) --------------------
 
@@ -678,6 +728,14 @@ def _pipeline_stats(pipeline: str, backend: str, n_segments: int,
         "bucket_rows": bucket_rows,
         "pipeline_calls": pipeline_calls,
     }
+
+
+def _row_index(rows: list[int]):
+    """Strictly ascending rows -> a slice where they are consecutive (a
+    view, not a gathered copy), else an index array."""
+    if rows[-1] - rows[0] == len(rows) - 1:
+        return slice(rows[0], rows[-1] + 1)
+    return np.array(rows, np.intp)
 
 
 def _empty() -> ProcessedSegments:

@@ -56,6 +56,24 @@ def store(tmp_path_factory):
 
 
 @pytest.fixture(scope="module")
+def wide_store(tmp_path_factory):
+    """One shard of one scaled hourly file: about a hundred tracks."""
+    root = tmp_path_factory.mktemp("wide_store")
+    raw, org, arc = (str(root / d) for d in ("raw", "org", "arc"))
+    write_scaled_dataset(raw, ScaledDatasetSpec(name="w", n_files=1,
+                                                scale=5e2))
+    organizer = Organizer(org, synthetic_registry(n=2000, seed=13))
+    for t in organize_tasks_from_dir(raw):
+        organizer(t)
+    archiver = Archiver(org, arc)
+    for t in archive_tasks_from_tree(org):
+        archiver(t)
+    path = str(root / "store")
+    assert len(build_store(arc, path, target_points=10 ** 6).shards) == 1
+    return path
+
+
+@pytest.fixture(scope="module")
 def screen_trace(tmp_path_factory):
     """Events of a traced barrier-screen workflow."""
     tr = Tracer()
@@ -122,6 +140,27 @@ def test_device_counters_sum_to_last_stats(store):
         == proc.last_stats["allocated_points"]
     pack = _spans(tr.events, "segments.pack")
     assert sum(e[6]["rows"] for e in pack) == proc.last_stats["n_segments"]
+
+
+def test_reassemble_span_counts_whole_bucket_gathers(wide_store):
+    """A shard task of many tracks: its reassembly span carries the
+    segments it reassembled and the gather-assignments it made, at most
+    one per pair of source bucket and width group."""
+    tr = Tracer()
+    tasks = segment_tasks_from_store(wide_store, granularity="shard")
+    proc = SegmentProcessor()
+    r = run_job(tasks, proc, backend="threads", n_workers=1, tracer=tr)
+    (span,) = _spans(tr.events, "segments.reassemble")
+    assert span[5] == tasks[0].task_id
+    segments, gathers = span[6]["segments"], span[6]["gathers"]
+    assert segments == proc.last_stats["n_segments"]
+    tracks = [ps for res in r.results.values() for ps in res.values()
+              if len(ps)]
+    assert len(tracks) > 1
+    groups = len({ps.lat.shape[1] for ps in tracks})
+    buckets = proc.last_stats["pipeline_calls"]
+    assert buckets <= gathers <= buckets * groups
+    assert 10 * gathers <= segments
 
 
 def test_screen_pass_stages_under_cell_tasks(screen_trace):

@@ -18,12 +18,13 @@ import pytest
 from hypothesis import given, settings
 from hypothesis import strategies as st
 
-from repro.geometry.aerodromes import synthetic_aerodromes
+from repro.geometry.aerodromes import Aerodrome, synthetic_aerodromes
 from repro.kernels import ops
 from repro.kernels.segment_pipeline import FIELDS
 from repro.tracks.segments import (
-    BUCKET_SIZES, MAX_SEG_POINTS, SegmentProcessor, _round_rows,
-    bucket_width, split_segments)
+    _PLANE_ATTRS, BUCKET_SIZES, MAX_SEG_POINTS, ProcessedSegments,
+    SegmentProcessor, _empty, _pipeline_stats, _round_rows, bucket_width,
+    split_segments)
 
 # Equatorial test grid: f32 lat/lon ulp is ~60x smaller near 0 than at
 # CONUS latitudes, so central-difference rates don't amplify the
@@ -253,6 +254,181 @@ def test_bucketing_reassembly_is_batch_composition_invariant(seed, n_arch):
         for attr in ATTRS:
             np.testing.assert_array_equal(
                 getattr(alone, attr), getattr(batched, attr), err_msg=attr)
+
+
+# ---------------------------------------------------------------------------
+# Reassembly: whole-bucket gathers == the per-row reference, bit for bit.
+# ---------------------------------------------------------------------------
+
+def _per_row_reassemble(self, items, records, buckets, fetched, allocated
+                        ) -> list[ProcessedSegments]:
+    """The per-row reassembly that whole-bucket gathers replaced, kept
+    as the reference (``self`` is a SegmentProcessor)."""
+    planes: dict[int, dict[str, np.ndarray]] = {}   # gi -> field rows
+    for idxs, host in fetched:
+        for r, gi in enumerate(idxs):
+            planes[gi] = {k: v[r] for k, v in host.items()}
+
+    # Airspace class for every segment in one vectorized query.
+    lat0 = np.array([planes[gi]["lat"][0] for gi in range(len(records))])
+    lon0 = np.array([planes[gi]["lon"][0] for gi in range(len(records))])
+    airspace = self._airspace_classes(lat0, lon0)
+
+    valid = sum(rec.m for rec in records)
+    bucket_rows: dict[int, int] = {}
+    for (width, _), ix in buckets.items():
+        bucket_rows[int(width)] = bucket_rows.get(int(width), 0) \
+            + len(ix)
+    self.last_stats = _pipeline_stats(
+        "fused", self.backend, len(records), int(valid),
+        int(allocated), bucket_rows, len(buckets))
+
+    out_list: list[ProcessedSegments] = []
+    gi = 0
+    for ai, (_, segs) in enumerate(items):
+        rows = list(range(gi, gi + len(segs)))
+        gi += len(segs)
+        if not rows:
+            out_list.append(_empty())
+            continue
+        wmax = max(records[r].width for r in rows)
+        fields = {attr: np.zeros((len(rows), wmax), np.float32)
+                  for _, attr in _PLANE_ATTRS}
+        for b, r in enumerate(rows):
+            w = records[r].width
+            for plane, attr in _PLANE_ATTRS:
+                fields[attr][b, :w] = planes[r][plane]
+        out_list.append(ProcessedSegments(
+            icao24=[records[r].name for r in rows],
+            count=np.array([records[r].m for r in rows], np.int32),
+            airspace=[airspace[r] for r in rows],
+            **fields))
+    return out_list
+
+
+#: (observations, seconds between them): the grid points fall in the
+#: 128, 256, 512 and 1024 buckets.
+_WIDTH_SHAPES = {128: (20, 5.0), 256: (50, 5.0), 512: (90, 5.0),
+                 1024: (150, 7.0)}
+
+
+def _shaped_archive(name, shapes):
+    """One archive of segments 400 s apart, one per (width, span):
+    ``span`` starts the segment just south of the DEM tile border at
+    40 deg N and drifts north across it (``_may_span`` True); otherwise
+    it stays near 35 deg N, inside one tile."""
+    ts, lats, lons = [], [], []
+    t = 0.0
+    for i, (width, span) in enumerate(shapes):
+        n, dt = _WIDTH_SHAPES[width]
+        ts.append(t + dt * np.arange(1, n + 1))
+        lat0 = 39.9 if span else 35.0 + 0.3 * i
+        lats.append(lat0 + (0.004 if span else 1e-4) * np.arange(n))
+        lons.append(-100.0 + 0.2 * i + 1e-3 * np.arange(n))
+        t = ts[-1][-1] + 400.0
+    obs = {"time": np.concatenate(ts), "lat": np.concatenate(lats),
+           "lon": np.concatenate(lons),
+           "alt": np.full(sum(len(x) for x in ts), 1500.0),
+           "icao24": np.array([name] * sum(len(x) for x in ts))}
+    segs = split_segments(obs["time"])
+    assert len(segs) == len(shapes)
+    return obs, segs
+
+
+def _no_segments():
+    return ({"time": np.array([0.0, 1.0]), "lat": np.zeros(2),
+             "lon": np.zeros(2), "alt": np.zeros(2),
+             "icao24": np.array(["e0e0e0"] * 2)}, [])
+
+
+def _reassembly_items(case):
+    mixed = [_shaped_archive("a00001", [(128, False), (1024, True),
+                                        (512, False), (256, True)]),
+             _shaped_archive("a00002", [(256, False), (128, True)]),
+             _shaped_archive("a00003", [(1024, False), (1024, True),
+                                        (128, False)])]
+    if case == "mixed_buckets":
+        return mixed
+    if case == "zero_segment_archives":
+        return [_no_segments(), mixed[0], _no_segments(), _no_segments(),
+                mixed[1], _no_segments()]
+    if case == "one_segment":
+        return [_shaped_archive("b00001", [(256, False)])]
+    rng = np.random.default_rng(29)
+    return [_shaped_archive(f"c{i:05x}", [(int(rng.choice(BUCKET_SIZES)),
+                                          bool(rng.random() < 0.3))])
+            for i in range(60)]
+
+
+def _noise_process_segments(dem, t_in, v_in, count_in, t_out, count_out,
+                            **kw):
+    """Stand-in for the device call: read-only (B, K) f32 planes of
+    noise over every column (so the reassembly must carry a bucket row
+    whole), starting at each row's first knot (so airspace classes read
+    real start points)."""
+    rng = np.random.default_rng(int(count_out.sum()) + t_out.shape[1])
+    out = {}
+    for f in FIELDS:
+        plane = rng.normal(0.0, 100.0, t_out.shape).astype(np.float32)
+        if f in ("lat", "lon"):
+            plane[:, 0] = v_in[:, 0 if f == "lat" else 1, 0]
+        plane.flags.writeable = False
+        out[f] = plane
+    return out
+
+
+@pytest.mark.parametrize("device", ["noise", "pipeline"])
+@pytest.mark.parametrize("case", ["mixed_buckets", "zero_segment_archives",
+                                  "one_segment", "many_one_segment"])
+def test_reassembly_matches_per_row_reference(case, device, monkeypatch):
+    items = _reassembly_items(case)
+    if device == "noise":
+        monkeypatch.setattr(ops, "process_segments",
+                            _noise_process_segments)
+    # Aerodromes on some segments' start points: classes other than G.
+    starts = [(obs["lat"][s.start], obs["lon"][s.start])
+              for obs, segs in items for s in segs][::3]
+    aero = [Aerodrome(f"X{i}", float(la), float(lo), "BCD"[i % 3], 0.0)
+            for i, (la, lo) in enumerate(starts)]
+    proc = SegmentProcessor(aerodromes=aero)
+    seen = {}
+    real = proc._reassemble
+
+    def spy(items, records, buckets, fetched, allocated):
+        seen["args"] = (items, records, buckets, list(fetched), allocated)
+        return real(items, records, buckets, fetched, allocated)
+
+    monkeypatch.setattr(proc, "_reassemble", spy)
+    got = proc._process_many(items)
+    ref = SegmentProcessor(aerodromes=aero)
+    want = _per_row_reassemble(ref, *seen["args"])
+    if case == "mixed_buckets":
+        assert {k[1] for k in seen["args"][2]} == {False, True}
+        assert {k[0] for k in seen["args"][2]} == set(BUCKET_SIZES)
+    assert proc.last_stats == ref.last_stats
+    assert len(got) == len(want) == len(items)
+    assert any(c != "G" for w in want for c in w.airspace)
+
+    def same(a, b):
+        return (a.dtype == b.dtype and a.shape == b.shape
+                and a.tobytes() == b.tobytes())
+
+    for g, w in zip(got, want):
+        assert g.icao24 == w.icao24 and g.airspace == w.airspace
+        assert same(g.count, w.count)
+        for attr in ATTRS + ("count",):
+            plane = getattr(g, attr)
+            assert same(plane, getattr(w, attr)), attr
+            assert plane.flags.writeable and plane.flags.c_contiguous
+    # A write into one track's planes leaves every other track's alone.
+    hit = next(i for i, g in enumerate(got) if len(g))
+    got[hit].lat[0, 0] += 1.0
+    for i, (g, w) in enumerate(zip(got, want)):
+        for attr in ATTRS:
+            if i == hit and attr == "lat":
+                assert not same(g.lat, w.lat)
+            else:
+                assert same(getattr(g, attr), getattr(w, attr)), (i, attr)
 
 
 # ---------------------------------------------------------------------------
