@@ -5,7 +5,9 @@ loads the manifest index, and serves three access patterns:
 
   * random access — ``read_track(track_id)`` reconstructs one track's
     observation dict bitwise-identically to what the CSV parse produced
-    at ingest;
+    at ingest, and ``read_tracks(ids)`` many of them, decoding only the
+    row blocks (:data:`repro.store.writer.BLOCK_POINTS`) that hold them,
+    each once;
   * planned batches — ``plan()`` turns the index into per-shard
     :class:`ReadPlan` s (fused-pipeline bucket histograms included,
     computed without touching payload bytes);
@@ -47,6 +49,9 @@ __all__ = ["STORE_URI_PREFIX", "is_store_uri", "make_store_uri",
            "parse_store_uri", "ReadPlan", "ShardBatch", "TrackStore"]
 
 STORE_URI_PREFIX = "store://"
+
+#: Per-point shard columns (``offsets`` indexes them per track).
+_POINT_COLUMNS = ("time", "lat", "lon", "alt", "icao_codes")
 
 
 def is_store_uri(path: object) -> bool:
@@ -124,8 +129,10 @@ class TrackStore:
         #: Optional :class:`repro.obs.Tracer`: shard decodes become
         #: ``store_decode`` stage spans (:func:`repro.obs.stage`: under
         #: the worker's track and task on a runtime worker thread, else
-        #: track = shard id; ``extra`` holds the shard's ``bytes``, its
-        #: id and the ``obs`` decoded), consumer blocking
+        #: track = shard id; ``extra`` holds the encoded ``bytes`` read,
+        #: the shard id, the row ``blocks`` decoded, the points they hold
+        #: (``obs_decoded``) and the points served, ``obs``), consumer
+        #: blocking
         #: becomes ``store_wait`` spans, and prefetch handoffs become
         #: instants.  Spans use the *tracer's* clock — not ``clock`` —
         #: so they share one timeline with scheduler/serving events.
@@ -237,52 +244,65 @@ class TrackStore:
     # -- decoding ---------------------------------------------------------
 
     def _decode_shard(self, plan: ReadPlan) -> ShardBatch:
+        """Decode the plan's tracks from the row blocks that hold them:
+        each block once, in block order; the tracks' rows are copied out
+        (:meth:`repro.store.codec.ShardView.read_ranges`), so the blocks
+        are released on return."""
         from repro.tracks.segments import split_segments
 
         rec = plan.shard
         t0 = self._clock()
         tr = self.tracer
-        with stage(tr, "store_decode", "store", rec.shard_id) as st:
-            path = os.path.join(self.root, rec.filename)
-            cols, meta = codec.read_shard(path)
-            offsets = cols["offsets"]
-            values = meta.get("icao_values", [])
-            items: list[tuple[dict, list[slice]]] = []
-            track_ids: list[str] = []
+        with stage(tr, "store_decode", "store", rec.shard_id) as st, \
+                codec.ShardView(os.path.join(self.root,
+                                             rec.filename)) as view:
+            offsets = view.read("offsets")
+            rows = np.fromiter((t.row for t in plan.tracks), np.int64,
+                               len(plan.tracks))
+            lo, hi = offsets[rows], offsets[rows + 1]
+            cols = {name: view.read_ranges(name, lo, hi)
+                    for name in _POINT_COLUMNS}
+            values = view.meta.get("icao_values", [])
             value_arr = (np.asarray(values) if values
                          else np.zeros(0, dtype="U1"))
-            for t in plan.tracks:
-                lo, hi = int(offsets[t.row]), int(offsets[t.row + 1])
-                codes = cols["icao_codes"][lo:hi]
-                names = (value_arr[codes] if len(codes)
-                         else np.zeros(0, dtype="U1"))
-                obs = {
-                    "time": cols["time"][lo:hi],
-                    "lat": cols["lat"][lo:hi],
-                    "lon": cols["lon"][lo:hi],
-                    "alt": cols["alt"][lo:hi],
-                    "icao24": names,
-                }
-                items.append((obs, split_segments(obs["time"])))
-                track_ids.append(t.track_id)
+            items: list[tuple[dict, list[slice]]] = []
+            for times, lat, lon, alt, codes in zip(
+                    cols["time"], cols["lat"], cols["lon"], cols["alt"],
+                    cols["icao_codes"]):
+                obs = {"time": times, "lat": lat, "lon": lon, "alt": alt,
+                       "icao24": (value_arr[codes] if len(codes)
+                                  else np.zeros(0, dtype="U1"))}
+                items.append((obs, split_segments(times)))
             if tr is not None:
-                st.extra = {"bytes": rec.size_bytes, "shard": rec.shard_id,
-                            "obs": sum(len(o["time"]) for o, _ in items)}
+                st.extra = {"bytes": view.enc_bytes, "shard": rec.shard_id,
+                            "obs": int((hi - lo).sum()),
+                            "obs_decoded": view.rows.get("time", 0),
+                            "blocks": view.column_blocks.get("time", 0)}
         self.stats["shards_read"] += 1
-        self.stats["bytes_read"] += rec.size_bytes
+        self.stats["bytes_read"] += view.enc_bytes
         self.stats["decode_s"] += self._clock() - t0
-        return ShardBatch(shard_id=rec.shard_id, track_ids=track_ids,
+        return ShardBatch(shard_id=rec.shard_id,
+                          track_ids=[t.track_id for t in plan.tracks],
                           items=items)
 
     # -- access patterns ---------------------------------------------------
 
+    def read_tracks(self, track_ids: Sequence[str]
+                    ) -> dict[str, tuple[dict, list[slice]]]:
+        """Many tracks -> ``{track_id: (obs, segs)}``.  The reads go in
+        (shard, block) order: each shard touched is one decode of the
+        blocks that hold the wanted rows, each block decoded once, and
+        no returned array keeps a decoded block alive."""
+        out: dict[str, tuple[dict, list[slice]]] = {}
+        for plan in self.plan([{"track": t} for t in track_ids]):
+            batch = self._decode_shard(plan)
+            out.update(zip(batch.track_ids, batch.items))
+        return out
+
     def read_track(self, track_id: str) -> dict[str, np.ndarray]:
-        """One track's observation dict (bitwise equal to ingest input)."""
-        t = self._track(track_id)
-        plan = self.plan([{"track": track_id}])[0]
-        batch = self._decode_shard(plan)
-        assert batch.track_ids == [t.track_id]
-        return batch.items[0][0]
+        """One track's observation dict (bitwise equal to ingest input),
+        decoded from the track's own row blocks."""
+        return self.read_tracks([track_id])[track_id][0]
 
     def read_shard_batch(self, shard_id: str) -> ShardBatch:
         """Decode ONE whole shard into a :class:`ShardBatch` (items in

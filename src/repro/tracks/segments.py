@@ -325,6 +325,9 @@ class SegmentProcessor:
         ProcessedSegments per zip/CSV/single-track task, a
         ``{track_id: ProcessedSegments}`` dict per multi-track
         ``store://`` task — with ONE fused pipeline pass over all of it.
+        The message's single-track ``store://`` tasks are read together
+        (:meth:`repro.store.reader.TrackStore.read_tracks`: each shard
+        block once); items keep the tasks' order.
         """
         out: dict[str, object] = {}
         items: list[tuple[dict, list[slice]]] = []
@@ -332,20 +335,24 @@ class SegmentProcessor:
         # task's result IS the ProcessedSegments, else it lands in the
         # task's per-track dict under that key.
         slots: list[tuple[str, Optional[str], int]] = []
+        singles = self._read_single_tracks(tasks)
         for task in tasks:
             path = task.payload or task.task_id
             if _is_store_uri(path):
-                _root, sel = _parse_store_uri(path)
-                single = "track" in sel
-                if not single:
-                    out[task.task_id] = {}
-                for tid, obs, segs in self._store_items(path):
-                    key = None if single else tid
+                root, sel = _parse_store_uri(path)
+                if "track" in sel:
+                    obs, segs = singles[root][sel["track"]]
                     if segs:
-                        slots.append((task.task_id, key, len(items)))
+                        slots.append((task.task_id, None, len(items)))
                         items.append((obs, segs))
-                    elif single:
+                    else:
                         out[task.task_id] = _empty()
+                    continue
+                out[task.task_id] = {}
+                for tid, obs, segs in self._store_items(path):
+                    if segs:
+                        slots.append((task.task_id, tid, len(items)))
+                        items.append((obs, segs))
                     else:
                         out[task.task_id][tid] = _empty()
                 continue
@@ -364,6 +371,21 @@ class SegmentProcessor:
                 else:
                     out[task_id][key] = processed[idx]
         return out
+
+    def _read_single_tracks(self, tasks: Sequence[Task]
+                            ) -> dict[str, dict[str, tuple]]:
+        """The single-track ``store://`` tasks of a message, read with
+        one grouped read per store: ``{root: {track_id: (obs, segs)}}``."""
+        wanted: dict[str, list[str]] = {}
+        for task in tasks:
+            path = task.payload or task.task_id
+            if _is_store_uri(path):
+                root, sel = _parse_store_uri(path)
+                if "track" in sel:
+                    wanted.setdefault(root, []).append(sel["track"])
+        return {root: self._store_read(root,
+                                       lambda st: st.read_tracks(ids))
+                for root, ids in wanted.items()}
 
     def _process_many(self, items: list[tuple[dict, list[slice]]]
                       ) -> list[ProcessedSegments]:

@@ -14,3 +14,14 @@ try:
     import hypothesis  # noqa: F401
 except ImportError:
     sys.path.insert(0, os.path.join(os.path.dirname(__file__), "_compat"))
+
+
+import pytest  # noqa: E402
+
+from _blockstore import build_block_store  # noqa: E402
+
+
+@pytest.fixture(scope="session")
+def block_store(tmp_path_factory):
+    """A store of 320 short tracks in 4-5 shards of dozens of blocks."""
+    return build_block_store(str(tmp_path_factory.mktemp("block_store")))

@@ -509,3 +509,44 @@ def test_airspace_classes_no_aerodromes():
     proc = SegmentProcessor()
     assert proc._airspace_classes(np.array([40.0]),
                                   np.array([-100.0])) == ["G"]
+
+
+# ---------------------------------------------------------------------------
+# A message of single-track store tasks: one grouped read, one pass.
+# ---------------------------------------------------------------------------
+
+def test_process_batch_of_300_single_track_tasks_matches_per_id(
+        block_store, monkeypatch):
+    """A 300-task message of single-track ``store://`` tasks, in random
+    order, == each task processed alone, bit for bit; the message reads
+    each shard it touches once."""
+    from repro.obs import Tracer
+    from repro.store import StoreManifest
+    from repro.tracks.segments import segment_tasks_from_store
+    tasks = segment_tasks_from_store(block_store, granularity="track")
+    rng = np.random.default_rng(8)
+    tasks = [tasks[i] for i in rng.permutation(len(tasks))[:300]]
+    proc = SegmentProcessor(aerodromes=synthetic_aerodromes(n=16))
+    alone = {t.task_id: proc.process_batch([t])[t.task_id] for t in tasks}
+    tr = Tracer()
+    proc.attach_tracer(tr)
+    batched = proc.process_batch(tasks)
+    proc.attach_tracer(None)
+    assert set(batched) == set(alone)
+    for tid, want in alone.items():
+        got = batched[tid]
+        assert got.icao24 == want.icao24
+        assert got.airspace == want.airspace
+        assert got.count.dtype == want.count.dtype
+        np.testing.assert_array_equal(got.count, want.count)
+        for attr in ATTRS:
+            a, b = getattr(got, attr), getattr(want, attr)
+            assert a.dtype == b.dtype and a.shape == b.shape, attr
+            assert a.tobytes() == b.tobytes(), (tid, attr)
+    decodes = [e for e in tr.events if e[2] == "store_decode"]
+    shards = [e[6]["shard"] for e in decodes]
+    assert shards == sorted(set(shards))
+    n_obs = {t.track_id: t.n_obs
+             for t in StoreManifest.load(block_store).tracks}
+    assert sum(e[6]["obs"] for e in decodes) == sum(n_obs[t.task_id]
+                                                    for t in tasks)

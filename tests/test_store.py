@@ -611,3 +611,223 @@ def test_token_shards_are_store_shards(tmp_path):
     with pytest.raises(RuntimeError, match="failed"):
         SelfScheduledLoader(shards, batch_size=2, seq_len=32,
                             n_ingest_workers=2, poll_interval=0.003)
+
+
+# ---------------------------------------------------------------------------
+# Row blocks: range decodes, grouped reads, the version-1 layout.
+# ---------------------------------------------------------------------------
+
+from _blockstore import SMALL_BLOCK, build_block_store  # noqa: E402
+
+_POINT_COLS = ("time", "lat", "lon", "alt", "icao_codes")
+
+
+def _full_decode(store_root, shard):
+    cols, meta = codec.read_shard(os.path.join(store_root, shard.filename))
+    return cols, meta
+
+
+def _block_case(store, manifest, case):
+    """(shard, tracks) for one case: a track inside one block, a track
+    across a block edge, or every track of a shard."""
+    shard = manifest.shards[1]
+    rows = manifest.tracks_in(shard.shard_id)
+    if case == "whole_shard":
+        return shard, rows
+    cols, _ = _full_decode(store, shard)
+    off = cols["offsets"]
+    for t in rows:
+        lo, hi = int(off[t.row]), int(off[t.row + 1])
+        across = lo // SMALL_BLOCK != (hi - 1) // SMALL_BLOCK
+        if across == (case == "across_blocks"):
+            return shard, [t]
+    raise AssertionError(f"no track for {case}")
+
+
+@pytest.mark.parametrize("case", ["inside_one_block", "across_blocks",
+                                  "whole_shard"])
+def test_block_reads_equal_slices_of_a_full_decode(block_store, case):
+    """A track's rows decoded from its blocks == the slice of the whole
+    shard's decode, bit for bit, from exactly the covering blocks."""
+    from repro.obs import Tracer
+    manifest = StoreManifest.load(block_store)
+    shard, tracks = _block_case(block_store, manifest, case)
+    cols, meta = _full_decode(block_store, shard)
+    off = cols["offsets"]
+    tr = Tracer()
+    store = TrackStore(block_store, tracer=tr)
+    got = store.read_tracks([t.track_id for t in tracks])
+    want_blocks = set()
+    for t in tracks:
+        lo, hi = int(off[t.row]), int(off[t.row + 1])
+        obs, segs = got[t.track_id]
+        for name in ("time", "lat", "lon", "alt"):
+            assert obs[name].dtype == cols[name].dtype
+            assert obs[name].tobytes() == cols[name][lo:hi].tobytes()
+        names = np.asarray(meta["icao_values"])[cols["icao_codes"][lo:hi]]
+        np.testing.assert_array_equal(obs["icao24"], names)
+        assert segs == split_segments(cols["time"][lo:hi])
+        want_blocks |= set(range(lo // SMALL_BLOCK,
+                                 (hi - 1) // SMALL_BLOCK + 1))
+        # range decodes of the codec itself
+        part, _ = codec.read_shard(
+            os.path.join(block_store, shard.filename),
+            columns=list(_POINT_COLS), rows=(lo, hi))
+        for name in _POINT_COLS:
+            assert part[name].tobytes() == cols[name][lo:hi].tobytes()
+    (span,) = [e for e in tr.events if e[2] == "store_decode"]
+    extra = span[6]
+    assert extra["blocks"] == len(want_blocks)
+    assert extra["obs"] == sum(t.n_obs for t in tracks)
+    assert extra["obs_decoded"] == sum(
+        min(SMALL_BLOCK, len(cols["time"]) - b * SMALL_BLOCK)
+        for b in want_blocks)
+    assert 0 < extra["bytes"] <= shard.size_bytes
+    if case != "whole_shard":
+        assert extra["obs_decoded"] < len(cols["time"])
+        assert extra["bytes"] < shard.size_bytes // 4
+
+
+def _encode_version1(columns, meta):
+    """The version-1 writer: one block per column, scalar block keys."""
+    import json as _json
+    import zlib
+    entries, blocks = [], []
+    for name in sorted(columns):
+        arr = np.ascontiguousarray(columns[name])
+        raw = arr.tobytes()
+        enc = zlib.compress(raw, codec.ZLIB_LEVEL)
+        c = "zlib"
+        if len(enc) >= len(raw):
+            enc, c = raw, "none"
+        entries.append({"name": name, "dtype": arr.dtype.str,
+                        "shape": list(arr.shape), "codec": c,
+                        "raw_bytes": len(raw), "enc_bytes": len(enc),
+                        "crc32": zlib.crc32(raw) & 0xFFFFFFFF})
+        blocks.append(enc)
+    hdr = _json.dumps({"version": 1, "columns": entries, "meta": meta},
+                      sort_keys=True, separators=(",", ":")).encode()
+    return (codec.MAGIC + (1).to_bytes(4, "little")
+            + len(hdr).to_bytes(8, "little")
+            + (zlib.crc32(hdr) & 0xFFFFFFFF).to_bytes(4, "little")
+            + hdr + b"".join(blocks))
+
+
+def test_single_block_version1_shard_still_decodes(tmp_path):
+    """A shard whose columns are single blocks (the version-1 layout)
+    decodes whole and by row range, also through the reader."""
+    store = build_block_store(str(tmp_path), n_tracks=30, seed=5,
+                              target_points=10 ** 6)
+    manifest = StoreManifest.load(store)
+    (shard,) = manifest.shards
+    path = os.path.join(store, shard.filename)
+    cols, meta = codec.read_shard(path)
+    old = _encode_version1(cols, meta)
+    dec, meta1 = codec.decode_shard(old)
+    assert meta1 == meta
+    for name, arr in cols.items():
+        assert dec[name].dtype == arr.dtype
+        assert dec[name].tobytes() == arr.tobytes()
+    part, _ = codec.decode_shard(old, columns=["lat"], rows=(100, 300))
+    assert part["lat"].tobytes() == cols["lat"][100:300].tobytes()
+    want = TrackStore(store).read_tracks(
+        [t.track_id for t in manifest.tracks])
+    with open(path, "wb") as f:
+        f.write(old)
+    got = TrackStore(store).read_tracks(
+        [t.track_id for t in manifest.tracks])
+    for tid, (obs, segs) in want.items():
+        assert got[tid][1] == segs
+        for name, arr in obs.items():
+            np.testing.assert_array_equal(got[tid][0][name], arr)
+
+
+def test_grouped_read_equals_read_track_and_decodes_each_block_once(
+        block_store, monkeypatch):
+    """read_tracks == read_track per id, bit for bit; each (shard,
+    column, block) is decompressed once, in (shard, block) order; no
+    returned array is a view of a decoded block."""
+    manifest = StoreManifest.load(block_store)
+    rng = np.random.default_rng(3)
+    ids = [manifest.tracks[i].track_id for i in rng.permutation(
+        len(manifest.tracks))[:120]]
+    store = TrackStore(block_store)
+    per_id = {tid: store.read_track(tid) for tid in ids}
+    seen = []
+    orig = codec.ShardView.block
+
+    def counted(view, name, i):
+        seen.append((view.meta["shard_id"], name, i))
+        return orig(view, name, i)
+
+    monkeypatch.setattr(codec.ShardView, "block", counted)
+    got = store.read_tracks(ids)
+    assert set(got) == set(ids)
+    assert len(seen) == len(set(seen))
+    shard_order = [s for s, _, _ in seen]
+    assert shard_order == sorted(shard_order)
+    for name in _POINT_COLS:
+        blocks = [(s, i) for s, n, i in seen if n == name]
+        assert blocks == sorted(blocks)
+    served: dict = {}
+    for tid in ids:
+        t = manifest.track(tid)
+        served[t.shard_id] = served.get(t.shard_id, 0) + t.n_obs
+    for tid in ids:
+        obs, segs = got[tid]
+        assert segs == split_segments(per_id[tid]["time"])
+        for name, arr in obs.items():
+            # a slice of one array that holds only the shard's served
+            # rows, never a view of a decoded block
+            owner = arr if arr.base is None else arr.base
+            assert isinstance(owner, np.ndarray) and owner.base is None
+            assert len(owner) <= served[manifest.track(tid).shard_id]
+            assert arr.dtype == per_id[tid][name].dtype
+            assert arr.tobytes() == per_id[tid][name].tobytes()
+
+
+def test_block_store_rebuild_is_byte_identical(tmp_path):
+    """Shards of many blocks: a rebuild gives the same shard bytes and
+    manifest, and every per-point column is split into blocks."""
+    a = build_block_store(str(tmp_path / "a"), n_tracks=80, seed=2)
+    b = build_block_store(str(tmp_path / "b"), n_tracks=80, seed=2)
+    ma, mb = StoreManifest.load(a), StoreManifest.load(b)
+    assert ma.shards == mb.shards and ma.tracks == mb.tracks
+    for s in ma.shards:
+        with open(os.path.join(a, s.filename), "rb") as fa, \
+                open(os.path.join(b, s.filename), "rb") as fb:
+            assert fa.read() == fb.read()
+        with codec.ShardView(os.path.join(a, s.filename)) as view:
+            for name in _POINT_COLS:
+                col = view.columns[name]
+                assert col.block_rows == min(SMALL_BLOCK, s.n_points)
+                assert len(col.blocks) == -(-s.n_points // SMALL_BLOCK)
+
+
+@settings(max_examples=10)
+@given(st.integers(min_value=0, max_value=700),
+       st.integers(min_value=1, max_value=100),
+       st.integers(min_value=0, max_value=10 ** 6))
+def test_codec_blocks_roundtrip_and_row_ranges(n, block_rows, seed):
+    """Any block size: whole decodes are bitwise equal, the encoding is
+    canonical, and every row range equals the slice of the whole."""
+    rng = np.random.default_rng(seed)
+    cols = {"f": _column("<f8", seed, n), "u": _column("<u4", seed + 1, n),
+            "m": rng.standard_normal((n, 3)).astype("<f4")}
+    data = codec.encode_shard(cols, meta={"n": n}, block_rows=block_rows)
+    assert data == codec.encode_shard(cols, meta={"n": n},
+                                      block_rows=block_rows)
+    whole, meta = codec.decode_shard(data)
+    assert meta == {"n": n}
+    for name, arr in cols.items():
+        assert whole[name].shape == arr.shape
+        assert whole[name].tobytes() == arr.tobytes()
+    for _ in range(4):
+        lo = int(rng.integers(0, n + 1))
+        hi = int(rng.integers(lo, n + 1))
+        part, _ = codec.decode_shard(data, rows=(lo, hi))
+        for name, arr in cols.items():
+            assert part[name].tobytes() == arr[lo:hi].tobytes()
+    view = codec.ShardView(data)
+    view.read("f", 0, min(n, 1))
+    assert view.column_blocks.get("f", 0) == min(n, 1)
