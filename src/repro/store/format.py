@@ -27,13 +27,17 @@ import os
 from typing import Any, Optional
 
 __all__ = ["STORE_FORMAT", "MANIFEST_NAME", "SHARD_DIR", "SHARD_SUFFIX",
-           "TrackRecord", "ShardRecord", "StoreManifest",
+           "POINT_COLUMNS", "TrackRecord", "ShardRecord", "StoreManifest",
            "fsync_dir", "write_atomic"]
 
 STORE_FORMAT = "repro.store/v1"
 MANIFEST_NAME = "store_manifest.json"
 SHARD_DIR = "shards"
 SHARD_SUFFIX = ".shard"
+
+#: Per-point shard columns, stored as one row group (``offsets``, one
+#: row per track and one more, indexes them per track).
+POINT_COLUMNS = ("time", "lat", "lon", "alt", "icao_codes")
 
 
 def fsync_dir(path: str) -> None:

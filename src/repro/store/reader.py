@@ -6,8 +6,8 @@ loads the manifest index, and serves three access patterns:
   * random access — ``read_track(track_id)`` reconstructs one track's
     observation dict bitwise-identically to what the CSV parse produced
     at ingest, and ``read_tracks(ids)`` many of them, decoding only the
-    row blocks (:data:`repro.store.writer.BLOCK_POINTS`) that hold them,
-    each once;
+    row-group units (:data:`repro.store.writer.BLOCK_POINTS` rows of
+    every point column) that hold them, each once;
   * planned batches — ``plan()`` turns the index into per-shard
     :class:`ReadPlan` s (fused-pipeline bucket histograms included,
     computed without touching payload bytes);
@@ -43,15 +43,13 @@ import numpy as np
 
 from repro.obs.tracer import stage
 from repro.store import codec
-from repro.store.format import ShardRecord, StoreManifest, TrackRecord
+from repro.store.format import (
+    POINT_COLUMNS, ShardRecord, StoreManifest, TrackRecord)
 
 __all__ = ["STORE_URI_PREFIX", "is_store_uri", "make_store_uri",
            "parse_store_uri", "ReadPlan", "ShardBatch", "TrackStore"]
 
 STORE_URI_PREFIX = "store://"
-
-#: Per-point shard columns (``offsets`` indexes them per track).
-_POINT_COLUMNS = ("time", "lat", "lon", "alt", "icao_codes")
 
 
 def is_store_uri(path: object) -> bool:
@@ -131,10 +129,10 @@ class TrackStore:
         #: the worker's track and task on a runtime worker thread, else
         #: track = shard id; ``extra`` holds the encoded ``bytes`` read,
         #: the shard id, the row ``blocks`` decoded, the points they hold
-        #: (``obs_decoded``) and the points served, ``obs``), consumer
-        #: blocking
-        #: becomes ``store_wait`` spans, and prefetch handoffs become
-        #: instants.  Spans use the *tracer's* clock — not ``clock`` —
+        #: (``obs_decoded``), the points served, ``obs``, and the
+        #: compressed ``units`` inflated, of all columns), consumer
+        #: blocking becomes ``store_wait`` spans, and prefetch handoffs
+        #: become instants.  Spans use the *tracer's* clock — not ``clock`` —
         #: so they share one timeline with scheduler/serving events.
         self.tracer = tracer
         #: Monotonic time source for the ``decode_s``/``wait_s`` stats.
@@ -244,10 +242,10 @@ class TrackStore:
     # -- decoding ---------------------------------------------------------
 
     def _decode_shard(self, plan: ReadPlan) -> ShardBatch:
-        """Decode the plan's tracks from the row blocks that hold them:
-        each block once, in block order; the tracks' rows are copied out
-        (:meth:`repro.store.codec.ShardView.read_ranges`), so the blocks
-        are released on return."""
+        """Decode the plan's tracks from the units that hold them: each
+        unit once, in order, for every point column; the tracks' rows
+        are copied out (:meth:`repro.store.codec.ShardView.read_columns`),
+        so the units are released on return."""
         from repro.tracks.segments import split_segments
 
         rec = plan.shard
@@ -260,8 +258,7 @@ class TrackStore:
             rows = np.fromiter((t.row for t in plan.tracks), np.int64,
                                len(plan.tracks))
             lo, hi = offsets[rows], offsets[rows + 1]
-            cols = {name: view.read_ranges(name, lo, hi)
-                    for name in _POINT_COLUMNS}
+            cols = view.read_columns(POINT_COLUMNS, lo, hi)
             values = view.meta.get("icao_values", [])
             value_arr = (np.asarray(values) if values
                          else np.zeros(0, dtype="U1"))
@@ -277,7 +274,8 @@ class TrackStore:
                 st.extra = {"bytes": view.enc_bytes, "shard": rec.shard_id,
                             "obs": int((hi - lo).sum()),
                             "obs_decoded": view.rows.get("time", 0),
-                            "blocks": view.column_blocks.get("time", 0)}
+                            "blocks": view.column_blocks.get("time", 0),
+                            "units": view.units}
         self.stats["shards_read"] += 1
         self.stats["bytes_read"] += view.enc_bytes
         self.stats["decode_s"] += self._clock() - t0
@@ -290,9 +288,9 @@ class TrackStore:
     def read_tracks(self, track_ids: Sequence[str]
                     ) -> dict[str, tuple[dict, list[slice]]]:
         """Many tracks -> ``{track_id: (obs, segs)}``.  The reads go in
-        (shard, block) order: each shard touched is one decode of the
-        blocks that hold the wanted rows, each block decoded once, and
-        no returned array keeps a decoded block alive."""
+        (shard, unit) order: each shard touched is one decode of the
+        units that hold the wanted rows, each unit decoded once, and no
+        returned array keeps a decoded unit alive."""
         out: dict[str, tuple[dict, list[slice]]] = {}
         for plan in self.plan([{"track": t} for t in track_ids]):
             batch = self._decode_shard(plan)
@@ -301,7 +299,7 @@ class TrackStore:
 
     def read_track(self, track_id: str) -> dict[str, np.ndarray]:
         """One track's observation dict (bitwise equal to ingest input),
-        decoded from the track's own row blocks."""
+        decoded from the track's own units."""
         return self.read_tracks([track_id])[track_id][0]
 
     def read_shard_batch(self, shard_id: str) -> ShardBatch:

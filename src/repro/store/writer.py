@@ -6,9 +6,10 @@ that parse exactly once: it walks an organized CSV tree or a PR-0
 archive tree, decodes each aircraft's observations, and packs the
 columns (time/lat/lon/alt as contiguous float64 + per-track offsets)
 into checksummed shards (:mod:`repro.store.codec`), sized so one shard
-is one healthy batch for the PR-3 length-bucketed fused pipeline, each
-column in row blocks of :data:`BLOCK_POINTS` so a single track is read
-from its own blocks.
+is one healthy batch for the PR-3 length-bucketed fused pipeline.  The
+per-point columns are one row group, in units of :data:`BLOCK_POINTS`
+rows of all of them, so a single track is read from its own units, each
+inflated once for every column.
 
 Segment shapes (``seg_knots``/``seg_grid``) are computed at ingest and
 recorded in the manifest, so the reader bins segments into buckets from
@@ -38,8 +39,8 @@ import numpy as np
 
 from repro.store import codec
 from repro.store.format import (
-    SHARD_DIR, SHARD_SUFFIX, ShardRecord, StoreManifest, TrackRecord,
-    write_atomic)
+    POINT_COLUMNS, SHARD_DIR, SHARD_SUFFIX, ShardRecord, StoreManifest,
+    TrackRecord, write_atomic)
 
 __all__ = ["DEFAULT_TARGET_POINTS", "BLOCK_POINTS", "EST_BYTES_PER_OBS",
            "ShardPlan",
@@ -53,10 +54,11 @@ __all__ = ["DEFAULT_TARGET_POINTS", "BLOCK_POINTS", "EST_BYTES_PER_OBS",
 #: batch runs near-full rows.
 DEFAULT_TARGET_POINTS = 131_072
 
-#: Rows per compressed block of every shard column.  A single-track read
-#: decodes only the blocks that hold the track's rows; 2,048 points (16
-#: KiB of float64) keeps a random 300-id message's decode within ~12-16x
-#: of the points it serves while whole-shard decodes stay zlib-bound.
+#: Rows per compressed unit of the point columns (and per block of
+#: ``offsets``).  A single-track read decodes only the units that hold
+#: the track's rows; 2,048 points (72 KiB of the five columns) keeps a
+#: random 300-id message's decode within ~12-16x of the points it serves
+#: while whole-shard decodes stay zlib-bound.
 BLOCK_POINTS = 2048
 
 #: Rough CSV bytes per observation row (scaled OpenSky state vectors);
@@ -182,7 +184,8 @@ def build_shard(out_root: str, plan: ShardPlan, *,
             "track_ids": [t.track_id for t in tracks],
             "icao_values": icao_values}
     data = codec.encode_shard(columns, meta=meta, compression=compression,
-                              block_rows=BLOCK_POINTS)
+                              block_rows=BLOCK_POINTS,
+                              groups=[POINT_COLUMNS])
     filename = f"{SHARD_DIR}/{plan.shard_id}{SHARD_SUFFIX}"
     write_atomic(os.path.join(out_root, filename), data)
     rec = ShardRecord(

@@ -28,7 +28,9 @@ fused pipeline behind the store's async prefetcher.
 
 from __future__ import annotations
 
+import ctypes
 import dataclasses
+import functools
 import os
 import zipfile
 from typing import Optional, Sequence
@@ -327,8 +329,15 @@ class SegmentProcessor:
         ``store://`` task — with ONE fused pipeline pass over all of it.
         The message's single-track ``store://`` tasks are read together
         (:meth:`repro.store.reader.TrackStore.read_tracks`: each shard
-        block once); items keep the tasks' order.
+        unit once); items keep the tasks' order.  Once the message's
+        transients are freed, the C heap's free pages go back to the OS
+        (:func:`_release_free_heap`).
         """
+        out = self._process_batch(tasks)
+        _release_free_heap()
+        return out
+
+    def _process_batch(self, tasks: Sequence[Task]) -> dict:
         out: dict[str, object] = {}
         items: list[tuple[dict, list[slice]]] = []
         # (task_id, track_key or None, item index); key None = the
@@ -750,6 +759,27 @@ def _pipeline_stats(pipeline: str, backend: str, n_segments: int,
         "bucket_rows": bucket_rows,
         "pipeline_calls": pipeline_calls,
     }
+
+
+@functools.cache
+def _malloc_trim():
+    """glibc's ``malloc_trim``, or None under another C library."""
+    fn = getattr(ctypes.CDLL(None), "malloc_trim", None)
+    if fn is not None:
+        fn.argtypes = [ctypes.c_size_t]
+        fn.restype = ctypes.c_int
+    return fn
+
+
+def _release_free_heap() -> None:
+    """Return the free pages of the C heap to the OS.  Each worker
+    thread allocates from its own glibc arena, which keeps the pages of
+    a message's freed transients (read columns, packing buffers, fetched
+    bucket planes) resident; without this the arenas of a run's worker
+    threads grow pass after pass, past a worker's memory slot."""
+    fn = _malloc_trim()
+    if fn is not None:
+        fn(0)
 
 
 def _row_index(rows: list[int]):

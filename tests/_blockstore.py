@@ -1,14 +1,15 @@
-"""A small store whose shards hold dozens of row blocks, for the tests
-of block reads (``tests/test_store.py``, ``tests/test_segment_pipeline.py``
-through the ``block_store`` fixture of ``conftest.py``)."""
+"""A small store whose shards hold dozens of row-group units, for the
+tests of unit reads (``tests/test_store.py``,
+``tests/test_segment_pipeline.py`` through the ``block_store`` fixture of
+``conftest.py``), and the version-2 layout of a shard's columns."""
 
 import os
 
 import numpy as np
 import pytest
 
-#: Rows per block of the ``block_store`` fixture: small, so that its
-#: shards hold dozens of blocks and many tracks straddle a block edge.
+#: Rows per unit of the ``block_store`` fixture: small, so that its
+#: shards hold dozens of units and many tracks straddle a unit edge.
 SMALL_BLOCK = 64
 
 
@@ -46,3 +47,22 @@ def build_block_store(root: str, n_tracks: int = 320, seed: int = 11,
         build_store(csv_dir, store, target_points=target_points)
     return store
 
+
+
+def encode_version2(columns: dict, meta: dict, block_rows: int) -> bytes:
+    """The version-2 layout of ``columns``: every column stored alone in
+    blocks of ``block_rows`` rows, no row groups."""
+    import json
+    import zlib
+
+    from repro.store import codec
+    data = codec.encode_shard(columns, meta=meta, block_rows=block_rows)
+    header, off = codec._parse_header(data)
+    del header["groups"]
+    header["version"] = 2
+    hdr = json.dumps(header, sort_keys=True,
+                     separators=(",", ":")).encode()
+    return (codec.MAGIC + (2).to_bytes(4, "little")
+            + len(hdr).to_bytes(8, "little")
+            + (zlib.crc32(hdr) & 0xFFFFFFFF).to_bytes(4, "little")
+            + hdr + data[off:])

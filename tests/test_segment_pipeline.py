@@ -550,3 +550,27 @@ def test_process_batch_of_300_single_track_tasks_matches_per_id(
              for t in StoreManifest.load(block_store).tracks}
     assert sum(e[6]["obs"] for e in decodes) == sum(n_obs[t.task_id]
                                                     for t in tasks)
+
+
+def test_process_batch_returns_free_heap_after_each_message(block_store,
+                                                            monkeypatch):
+    """Each message ends by handing the C heap's free pages back (glibc
+    ``malloc_trim``); the results are those of a run without it."""
+    from repro.tracks import segments
+    from repro.tracks.segments import segment_tasks_from_store
+    fn = segments._malloc_trim()
+    assert fn is None or fn(0) in (0, 1)
+    tasks = segment_tasks_from_store(block_store, granularity="track")[:40]
+    proc = SegmentProcessor(aerodromes=synthetic_aerodromes(n=16))
+    want = proc._process_batch(tasks)
+    calls = []
+    monkeypatch.setattr(segments, "_release_free_heap",
+                        lambda: calls.append(1))
+    got = [proc.process_batch(tasks[a:a + 10]) for a in range(0, 40, 10)]
+    assert len(calls) == 4
+    merged = {k: v for part in got for k, v in part.items()}
+    assert set(merged) == set(want)
+    for tid, ps in want.items():
+        for attr in ATTRS:
+            assert getattr(merged[tid], attr).tobytes() \
+                == getattr(ps, attr).tobytes()

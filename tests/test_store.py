@@ -614,10 +614,12 @@ def test_token_shards_are_store_shards(tmp_path):
 
 
 # ---------------------------------------------------------------------------
-# Row blocks: range decodes, grouped reads, the version-1 layout.
+# Row blocks and row-group units: range decodes, grouped reads, the
+# version-1 and version-2 layouts.
 # ---------------------------------------------------------------------------
 
-from _blockstore import SMALL_BLOCK, build_block_store  # noqa: E402
+from _blockstore import (  # noqa: E402
+    SMALL_BLOCK, build_block_store, encode_version2)
 
 _POINT_COLS = ("time", "lat", "lon", "alt", "icao_codes")
 
@@ -745,8 +747,8 @@ def test_single_block_version1_shard_still_decodes(tmp_path):
 def test_grouped_read_equals_read_track_and_decodes_each_block_once(
         block_store, monkeypatch):
     """read_tracks == read_track per id, bit for bit; each (shard,
-    column, block) is decompressed once, in (shard, block) order; no
-    returned array is a view of a decoded block."""
+    unit) is inflated once, in (shard, unit) order, for all the point
+    columns; no returned array is a view of a decoded unit."""
     manifest = StoreManifest.load(block_store)
     rng = np.random.default_rng(3)
     ids = [manifest.tracks[i].track_id for i in rng.permutation(
@@ -754,21 +756,24 @@ def test_grouped_read_equals_read_track_and_decodes_each_block_once(
     store = TrackStore(block_store)
     per_id = {tid: store.read_track(tid) for tid in ids}
     seen = []
-    orig = codec.ShardView.block
+    orig = codec.ShardView._units
 
-    def counted(view, name, i):
-        seen.append((view.meta["shard_id"], name, i))
-        return orig(view, name, i)
+    def counted(view, grp, need, wanted):
+        for u, raw in orig(view, grp, need, wanted):
+            seen.append((view.meta["shard_id"],
+                         tuple(c.name for c in grp.columns), u))
+            yield u, raw
 
-    monkeypatch.setattr(codec.ShardView, "block", counted)
+    monkeypatch.setattr(codec.ShardView, "_units", counted)
     got = store.read_tracks(ids)
     assert set(got) == set(ids)
     assert len(seen) == len(set(seen))
     shard_order = [s for s, _, _ in seen]
     assert shard_order == sorted(shard_order)
-    for name in _POINT_COLS:
-        blocks = [(s, i) for s, n, i in seen if n == name]
-        assert blocks == sorted(blocks)
+    units = [(s, i) for s, names, i in seen if names != ("offsets",)]
+    assert units == sorted(units)
+    assert {names for _, names, _ in seen} == {("offsets",),
+                                               tuple(sorted(_POINT_COLS))}
     served: dict = {}
     for tid in ids:
         t = manifest.track(tid)
@@ -787,8 +792,8 @@ def test_grouped_read_equals_read_track_and_decodes_each_block_once(
 
 
 def test_block_store_rebuild_is_byte_identical(tmp_path):
-    """Shards of many blocks: a rebuild gives the same shard bytes and
-    manifest, and every per-point column is split into blocks."""
+    """Shards of many units: a rebuild gives the same shard bytes and
+    manifest, and the per-point columns are one row group of units."""
     a = build_block_store(str(tmp_path / "a"), n_tracks=80, seed=2)
     b = build_block_store(str(tmp_path / "b"), n_tracks=80, seed=2)
     ma, mb = StoreManifest.load(a), StoreManifest.load(b)
@@ -798,10 +803,12 @@ def test_block_store_rebuild_is_byte_identical(tmp_path):
                 open(os.path.join(b, s.filename), "rb") as fb:
             assert fa.read() == fb.read()
         with codec.ShardView(os.path.join(a, s.filename)) as view:
-            for name in _POINT_COLS:
-                col = view.columns[name]
-                assert col.block_rows == min(SMALL_BLOCK, s.n_points)
-                assert len(col.blocks) == -(-s.n_points // SMALL_BLOCK)
+            grp = view.columns["time"].group
+            assert [c.name for c in grp.columns] == sorted(_POINT_COLS)
+            assert all(view.columns[n].group is grp for n in _POINT_COLS)
+            assert grp.block_rows == min(SMALL_BLOCK, s.n_points)
+            assert len(grp.units) == -(-s.n_points // SMALL_BLOCK)
+            assert grp.row_bytes == 4 * 8 + 4
 
 
 @settings(max_examples=10)
@@ -831,3 +838,231 @@ def test_codec_blocks_roundtrip_and_row_ranges(n, block_rows, seed):
     view = codec.ShardView(data)
     view.read("f", 0, min(n, 1))
     assert view.column_blocks.get("f", 0) == min(n, 1)
+
+
+def _point_columns(n, seed):
+    """The five point columns of ``n`` rows and an ``offsets`` column."""
+    rng = np.random.default_rng(seed)
+    cols = {name: rng.standard_normal(n).cumsum()
+            for name in ("time", "lat", "lon", "alt")}
+    cols["icao_codes"] = rng.integers(0, 7, n).astype(np.uint32)
+    cols["offsets"] = np.arange(0, n + 1, 9, dtype=np.int64)
+    return cols
+
+
+def _range_case(case, n, rng):
+    """(lo, hi) of one case of ranges over ``n`` rows (units of
+    ``SMALL_BLOCK``; ``n`` ends in a partial unit)."""
+    b = SMALL_BLOCK
+    if case == "whole_shard":                  # one run of tracks in order
+        cuts = np.unique(np.r_[0, rng.integers(0, n, 40), n])
+        return cuts[:-1], cuts[1:]
+    if case == "rows_at_unit_edges":
+        lo = np.r_[[k * b + d for k in range(1, n // b + 1) for d in (-1, 0)],
+                   n - 1, 0]
+        return lo, lo + 1
+    if case == "random_range_sets":
+        lo = rng.integers(0, n, 30)
+        return lo, np.minimum(lo + rng.integers(0, 3 * b, 30), n)
+    if case == "partial_last_unit":
+        last = n // b * b
+        return (np.array([last, n - 10, last - 5, n - 1]),
+                np.array([n, n, n, n]))
+    assert case == "empty_ranges"
+    return np.array([0, 5, 5, n, 70]), np.array([0, 5, 9, n, 70])
+
+
+@pytest.mark.parametrize("case", ["whole_shard", "rows_at_unit_edges",
+                                  "random_range_sets", "partial_last_unit",
+                                  "empty_ranges"])
+def test_grouped_reads_equal_version2_reads(case):
+    """Row ranges of the point columns read from a version-3 shard (one
+    row group) equal, bit for bit, the same reads of the version-2 layout
+    and the slices of the columns; the version-3 read inflates each unit
+    that holds a range once for all five columns, version 2 each block
+    of each column."""
+    n = 15 * SMALL_BLOCK + 40
+    cols = _point_columns(n, seed=7)
+    v3 = codec.encode_shard(cols, meta={}, block_rows=SMALL_BLOCK,
+                            groups=[_POINT_COLS])
+    v2 = encode_version2(cols, {}, SMALL_BLOCK)
+    assert v2[8:12] == (2).to_bytes(4, "little")
+    lo, hi = _range_case(case, n, np.random.default_rng(3))
+    units = {r // SMALL_BLOCK for a, b in zip(lo, hi) for r in range(a, b)}
+    views = [codec.ShardView(data) for data in (v3, v2)]
+    reads = [view.read_columns(_POINT_COLS, lo, hi) for view in views]
+    for name in _POINT_COLS:
+        for got3, got2, a, b in zip(reads[0][name], reads[1][name], lo, hi):
+            assert got3.dtype == got2.dtype == cols[name].dtype
+            assert got3.tobytes() == got2.tobytes() \
+                == cols[name][a:b].tobytes()
+    assert views[0].units == len(units)
+    assert views[1].units == len(_POINT_COLS) * len(units)
+    for view in views:
+        assert view.column_blocks.get("time", 0) == len(units)
+        assert view.rows.get("time", 0) == sum(
+            min(SMALL_BLOCK, n - u * SMALL_BLOCK) for u in units)
+    if case == "whole_shard":
+        whole3, _ = codec.decode_shard(v3)
+        whole2, _ = codec.decode_shard(v2)
+        for name, arr in cols.items():
+            assert whole3[name].tobytes() == whole2[name].tobytes() \
+                == arr.tobytes()
+
+
+def test_version2_store_still_decodes(tmp_path):
+    """A store whose shards are rewritten in the version-2 layout reads
+    the same tracks, with the same block counters; its reads count one
+    unit per (column, block), five times the row group's."""
+    from repro.obs import Tracer
+    store = build_block_store(str(tmp_path), n_tracks=60, seed=4)
+    manifest = StoreManifest.load(store)
+    ids = [t.track_id for t in manifest.tracks][::3]
+    runs = []
+    for layout in (3, 2):
+        if layout == 2:
+            for s in manifest.shards:
+                path = os.path.join(store, s.filename)
+                cols, meta = codec.read_shard(path)
+                with open(path, "wb") as f:
+                    f.write(encode_version2(cols, meta, SMALL_BLOCK))
+        tr = Tracer()
+        got = TrackStore(store, tracer=tr).read_tracks(ids)
+        runs.append((got, [e[6] for e in tr.events
+                           if e[2] == "store_decode"]))
+    (got3, spans3), (got2, spans2) = runs
+    for tid in ids:
+        assert got2[tid][1] == got3[tid][1]
+        for name, arr in got3[tid][0].items():
+            assert got2[tid][0][name].tobytes() == arr.tobytes()
+    for e3, e2 in zip(spans3, spans2):
+        for key in ("blocks", "obs", "obs_decoded"):
+            assert e3[key] == e2[key]
+        offsets_blocks = e3["units"] - e3["blocks"]
+        assert offsets_blocks >= 1
+        assert e2["units"] == 5 * e3["blocks"] + offsets_blocks
+
+
+@settings(max_examples=10)
+@given(st.integers(min_value=0, max_value=700),
+       st.integers(min_value=1, max_value=100),
+       st.integers(min_value=0, max_value=10 ** 6))
+def test_codec_groups_roundtrip_and_row_ranges(n, block_rows, seed):
+    """Any block size: a shard with a row group (of 1-D and 2-D columns)
+    beside columns stored alone encodes canonically, whatever the order
+    the groups are named in, decodes whole bit for bit, and every row
+    range equals the slice of the whole."""
+    rng = np.random.default_rng(seed)
+    cols = {"f": _column("<f8", seed, n), "u": _column("<u4", seed + 1, n),
+            "m": rng.standard_normal((n, 3)).astype("<f4"),
+            "alone": _column("<i8", seed + 2, n + 1)}
+    data = codec.encode_shard(cols, meta={"n": n}, block_rows=block_rows,
+                              groups=[["u", "m", "f"]])
+    assert data == codec.encode_shard(cols, meta={"n": n},
+                                      block_rows=block_rows,
+                                      groups=[("f", "m", "u")])
+    whole, meta = codec.decode_shard(data)
+    assert meta == {"n": n}
+    for name, arr in cols.items():
+        assert whole[name].shape == arr.shape
+        assert whole[name].tobytes() == arr.tobytes()
+    for _ in range(4):
+        lo = int(rng.integers(0, n + 1))
+        hi = int(rng.integers(lo, n + 1))
+        part, _ = codec.decode_shard(data, columns=["f", "m", "u"],
+                                     rows=(lo, hi))
+        for name in ("f", "m", "u"):
+            assert part[name].tobytes() == cols[name][lo:hi].tobytes()
+
+
+@pytest.mark.parametrize("compression", ["zlib", "none"])
+def test_corrupted_unit_raises_checksum_error(compression):
+    """A flipped byte inside one unit fails every read of that unit with
+    ShardChecksumError; the other units still read."""
+    n = 6 * SMALL_BLOCK
+    cols = _point_columns(n, seed=1)
+    data = bytearray(codec.encode_shard(
+        cols, compression=compression, block_rows=SMALL_BLOCK,
+        groups=[_POINT_COLS]))
+    grp = codec.ShardView(bytes(data)).columns["lat"].group
+    off, _codec, _raw, enc, _crc = grp.units[2]
+    data[off + enc // 2] ^= 0xFF
+    broken = bytes(data)
+    for name in _POINT_COLS:
+        with pytest.raises(ShardChecksumError):
+            codec.decode_shard(broken, columns=[name],
+                               rows=(2 * SMALL_BLOCK + 3,
+                                     2 * SMALL_BLOCK + 4))
+    with pytest.raises(ShardChecksumError):
+        codec.decode_shard(broken)
+    part, _ = codec.decode_shard(broken, columns=list(_POINT_COLS),
+                                 rows=(0, 2 * SMALL_BLOCK))
+    for name in _POINT_COLS:
+        assert part[name].tobytes() == cols[name][:2 * SMALL_BLOCK].tobytes()
+
+
+@pytest.mark.parametrize("groups", [
+    [("time", "offsets")],             # columns of different lengths
+    [("time", "lat"), ("lat", "lon")],  # a column in two groups
+    [("time", "speed")],               # a column the shard lacks
+    [()],                              # an empty group
+])
+def test_ill_formed_group_is_refused(groups):
+    cols = _point_columns(100, seed=2)
+    with pytest.raises(ValueError):
+        codec.encode_shard(cols, block_rows=SMALL_BLOCK, groups=groups)
+
+
+def test_reads_inflate_each_unit_once(tmp_path, monkeypatch):
+    """On a shard of 64 units: a whole-shard decode calls
+    ``zlib.decompress`` once per unit and once per ``offsets`` block; a
+    3-track random read exactly for the units that hold those tracks.
+    The ``units`` counter of each ``store_decode`` span says the same."""
+    import types
+    import zlib
+
+    from repro.obs import Tracer
+    store = build_block_store(str(tmp_path), n_tracks=150, seed=19,
+                              target_points=10 ** 6)
+    manifest = StoreManifest.load(store)
+    (shard,) = manifest.shards
+    with codec.ShardView(os.path.join(store, shard.filename)) as view:
+        grp = view.columns["time"].group
+        n_offsets = len(view.columns["offsets"].group.units)
+        assert all(u[1] == "zlib" for u in grp.units)
+    assert len(grp.units) == 64
+    calls = []
+
+    def decompress(data, **kw):
+        calls.append(len(data))
+        return zlib.decompress(data, **kw)
+
+    monkeypatch.setattr(codec, "zlib", types.SimpleNamespace(
+        decompress=decompress, crc32=zlib.crc32, compress=zlib.compress,
+        error=zlib.error))
+    tr = Tracer()
+    store_ = TrackStore(store, tracer=tr)
+    whole = store_.read_shard_batch(shard.shard_id)
+    assert len(calls) == 64 + n_offsets
+    tracks = [manifest.tracks[i] for i in
+              np.random.default_rng(5).choice(len(manifest.tracks), 3,
+                                              replace=False)]
+    off = np.asarray(codec.read_shard(os.path.join(store, shard.filename),
+                                      columns=["offsets"])[0]["offsets"])
+    calls.clear()
+    got = store_.read_tracks([t.track_id for t in tracks])
+    want = {u for t in tracks
+            for u in range(off[t.row] // SMALL_BLOCK,
+                           (off[t.row + 1] - 1) // SMALL_BLOCK + 1)}
+    assert len(calls) == len(want) + n_offsets
+    spans = [e[6] for e in tr.events if e[2] == "store_decode"]
+    assert [e["units"] for e in spans] == [64 + n_offsets,
+                                           len(want) + n_offsets]
+    assert [e["blocks"] for e in spans] == [64, len(want)]
+    for t in tracks:
+        obs, _segs = got[t.track_id]
+        (obs_whole, _), = [it for tid, it in zip(whole.track_ids,
+                                                 whole.items)
+                           if tid == t.track_id]
+        for name, arr in obs_whole.items():
+            assert obs[name].tobytes() == arr.tobytes()
