@@ -15,9 +15,6 @@ regression-gated:
     canonical serialization;
   * :mod:`repro.bench.campaign` — the ``python -m repro.bench.campaign``
     CLI (``--quick`` is the CI tier);
-  * :mod:`repro.bench.kernels` — kernel-level matrix: the fused segment
-    pipeline vs its unfused baseline (``BENCH_kernels.json``; also
-    ``python -m repro.bench.kernels`` / ``campaign --kernels``);
   * :mod:`repro.bench.storage` — storage-layer matrix: the columnar
     track store vs the CSV-zip path (``BENCH_storage.json``; also
     ``python -m repro.bench.storage`` / ``campaign --storage``);
@@ -31,15 +28,11 @@ from repro.bench.engine import (
 from repro.bench.paper import (
     PAPER_TABLE1, PAPER_TABLE2, TABLE_TOLERANCE, paper_scenarios,
     smoke_scenarios)
-from repro.bench.kernels import (
-    KernelScenario, KernelSpec, kernel_scenarios, run_kernel_campaign,
-    run_kernel_scenario)
 from repro.bench.scenarios import (
     Check, FAULT_PROFILES, FaultProfile, RunSpec, Scenario, expand)
 from repro.bench.schema import (
-    CAMPAIGN_SCHEMA, KERNELS_SCHEMA, SMOKE_SCHEMA, STORAGE_SCHEMA,
-    canonical_bytes, validate_campaign, validate_kernels,
-    validate_record, validate_storage)
+    CAMPAIGN_SCHEMA, SMOKE_SCHEMA, STORAGE_SCHEMA, canonical_bytes,
+    validate_campaign, validate_record, validate_storage)
 from repro.bench.storage import (
     StorageScenario, StorageSpec, run_storage_campaign,
     run_storage_scenario, storage_scenarios)
@@ -51,14 +44,11 @@ __all__ = [
     "paper_scenarios", "smoke_scenarios", "beyond_scenarios",
     "csv_rows", "execute_spec", "run_campaign", "run_scenario",
     "summary_lines",
-    "KernelScenario", "KernelSpec", "kernel_scenarios",
-    "run_kernel_campaign", "run_kernel_scenario",
     "StorageScenario", "StorageSpec", "storage_scenarios",
     "run_storage_campaign", "run_storage_scenario",
-    "CAMPAIGN_SCHEMA", "KERNELS_SCHEMA", "SMOKE_SCHEMA",
-    "STORAGE_SCHEMA",
-    "canonical_bytes", "validate_campaign", "validate_kernels",
-    "validate_record", "validate_storage",
+    "CAMPAIGN_SCHEMA", "SMOKE_SCHEMA", "STORAGE_SCHEMA",
+    "canonical_bytes", "validate_campaign", "validate_record",
+    "validate_storage",
 ]
 
 

@@ -60,18 +60,10 @@ def main(argv=None) -> int:
     ap.add_argument("--baseline", default=None, metavar="JSON",
                     help="previous BENCH_campaign.json to regression-gate "
                          "against")
-    ap.add_argument("--kernels", action="store_true",
-                    help="also run the kernel-level matrix "
-                         "(repro.bench.kernels) and write a second "
-                         "artifact next to --out")
-    ap.add_argument("--kernels-out", default="BENCH_kernels.json",
-                    metavar="JSON",
-                    help="artifact path for --kernels "
-                         "(default BENCH_kernels.json)")
     ap.add_argument("--storage", action="store_true",
                     help="also run the storage-layer matrix "
                          "(repro.bench.storage: columnar store vs "
-                         "CSV-zip) and write a third artifact")
+                         "CSV-zip) and write a second artifact")
     ap.add_argument("--storage-out", default="BENCH_storage.json",
                     metavar="JSON",
                     help="artifact path for --storage "
@@ -119,29 +111,6 @@ def main(argv=None) -> int:
     rc = 0
     if doc["summary"]["fail"] or doc["summary"]["error"]:
         rc = 1
-    if args.kernels:
-        from repro.bench.kernels import (
-            kernel_scenarios, kernel_summary_lines, run_kernel_campaign)
-        if not any(sc.matches(args.filter)
-                   and (not args.quick or sc.tier == "quick")
-                   for sc in kernel_scenarios()):
-            # campaign-group filters legitimately may not name any
-            # kernel cell; skip rather than fail the whole run
-            print("no kernel scenarios match --filter; skipping "
-                  "--kernels artifact")
-        else:
-            kdoc = run_kernel_campaign(quick=args.quick,
-                                       filters=args.filter,
-                                       seed=args.seed, progress=progress)
-            if args.kernels_out != "-":
-                with open(args.kernels_out, "w") as f:
-                    json.dump(kdoc, f, indent=1, sort_keys=True)
-                    f.write("\n")
-                print(f"wrote {args.kernels_out}")
-            for line in kernel_summary_lines(kdoc):
-                print(line)
-            if kdoc["summary"]["fail"] or kdoc["summary"]["error"]:
-                rc = 1
     if args.storage:
         from repro.bench.storage import (
             run_storage_campaign, storage_scenarios,

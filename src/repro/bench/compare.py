@@ -5,8 +5,6 @@ BENCH kind the repo emits:
 
   * ``repro.bench.campaign/v1`` / ``repro.bench.smoke/v1`` — headline
     deterministic metric ``job_seconds`` (simulated job time);
-  * ``repro.bench.kernels/v1`` — ``padded_fraction`` (padding-to-payload
-    ratio of the fused pipeline; multiplies wasted kernel compute);
   * ``repro.bench.storage/v1`` — ``bytes_per_point`` (columnar-store
     encoding efficiency);
   * ``repro.bench.scheduling/v1`` — ``makespan_seconds`` (simulated
@@ -70,7 +68,6 @@ METRIC = "job_seconds"          # historical default (campaign artifacts)
 DEFAULT_METRICS = {
     "repro.bench.campaign/v1": "job_seconds",
     "repro.bench.smoke/v1": "job_seconds",
-    "repro.bench.kernels/v1": "padded_fraction",
     "repro.bench.storage/v1": "bytes_per_point",
     "repro.bench.scheduling/v1": "makespan_seconds",
     "repro.bench.serving/v1": "ingest_lag_max_points",
@@ -173,8 +170,8 @@ def main(argv=None) -> int:
     ap = argparse.ArgumentParser(
         prog="python -m repro.bench.compare",
         description="Compare two BENCH artifacts (campaign, smoke, "
-                    "kernels, or storage — dispatched on their schema "
-                    "field) and fail on metric regressions.")
+                    "storage, ... — dispatched on their schema field) "
+                    "and fail on metric regressions.")
     ap.add_argument("old")
     ap.add_argument("new")
     ap.add_argument("--threshold", type=float, default=0.10,

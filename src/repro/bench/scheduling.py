@@ -1,12 +1,11 @@
 """Scheduling-policy benchmark matrix: policy x dataset x fault x backend.
 
 The campaign benchmarks the protocol against the paper's tables, the
-kernels matrix benchmarks the device hot path, the storage matrix the
-feed — this module benchmarks the *dispatch decisions* themselves: how
-much makespan, worker balance, and prefetch warmth each
-:mod:`repro.runtime.policies` policy buys on the workloads where the
-companion HPC paper says static chunking falls over (heavy-tailed task
-mixes + worker deaths).  Two cell kinds share one artifact
+storage matrix the feed — this module benchmarks the *dispatch
+decisions* themselves: how much makespan, worker balance, and prefetch
+warmth each :mod:`repro.runtime.policies` policy buys on the workloads
+where the companion HPC paper says static chunking falls over
+(heavy-tailed task mixes + worker deaths).  Two cell kinds share one artifact
 (``BENCH_scheduling.json``, schema ``repro.bench.scheduling/v1``):
 
   * ``sim`` cells — the discrete-event backend at bench scale: run a

@@ -1,8 +1,8 @@
 """Storage-layer benchmark scenarios: CSV-zip vs the columnar store.
 
-The campaign benchmarks the scheduler, the kernels matrix benchmarks the
-device hot path — this module benchmarks the layer that FEEDS that hot
-path: how fast ``(obs, segs)`` batches reach
+The campaign benchmarks the scheduler; the chip benchmark
+(``chipbench/``) measures the device hot path — this module benchmarks
+the layer that FEEDS that hot path: how fast ``(obs, segs)`` batches reach
 ``SegmentProcessor._process_many`` from disk.  It compares the paper's
 §III.A stopgap (zip archives whose CSV text is re-parsed every run)
 against :mod:`repro.store` (decoded columns, checksummed zlib shards,
@@ -62,7 +62,7 @@ class StorageSpec:
     phase: str = "warm"                 # cold | warm
     prefetch: int = 0                   # store only; decode-ahead depth
     consume: str = "feed"               # feed | pipeline
-    workload: str = "heavy_tail"        # repro.bench.kernels.WORKLOADS
+    workload: str = "heavy_tail"        # repro.tracks.datasets.WORKLOADS
     # Sized so the fixture spans several shards and a feed pass costs
     # tens of milliseconds — thread wakeups and timer noise must not
     # dominate the measured ratios the quick tier gates on.
@@ -74,7 +74,7 @@ class StorageSpec:
     seed: int = 0
 
     def __post_init__(self) -> None:
-        from repro.bench.kernels import WORKLOADS
+        from repro.tracks.datasets import WORKLOADS
         if self.source not in ("zip", "store"):
             raise ValueError(f"unknown source {self.source!r}")
         if self.phase not in ("cold", "warm"):
@@ -128,10 +128,10 @@ def _cleanup_fixtures() -> None:
 
 def _write_fixture(spec: StorageSpec) -> dict:
     """Synth archives -> CSVs -> zip tree -> store (built twice)."""
-    from repro.bench.kernels import KernelSpec, synth_items
     from repro.store import build_store
+    from repro.tracks.datasets import SegmentMixSpec, synth_items
 
-    items = synth_items(KernelSpec(
+    items = synth_items(SegmentMixSpec(
         workload=spec.workload, n_archives=spec.n_archives,
         segments_per_archive=spec.segments_per_archive, seed=spec.seed))
     root = tempfile.mkdtemp(prefix="repro-storage-bench-")

@@ -1,20 +1,15 @@
 """Fused, device-resident segment pipeline (one jit, zero host hops).
 
-The per-task hot path of the track workflow used to be three separate
-kernel launches with host numpy between them::
-
-    track_interp -> np.asarray -> fi/fj index math (host) -> agl_lookup
-                 -> np.asarray -> stack (host) -> dynamic_rates -> host
-
-Every arrow is a host<->device transfer and a sync point.  This module
-composes the three Pallas kernels plus the DEM fractional-index math and
-the padding masks under ONE ``jax.jit``: inputs go up once, the nine
-output planes come down once, and every intermediate (the resampled
-grid, fi/fj, tile origins, rate stack) stays on device.
+The per-task hot path of the track workflow composes the three Pallas
+kernels (``track_interp``, ``agl_lookup``, ``dynamic_rates``) plus the
+DEM fractional-index math and the padding masks under ONE ``jax.jit``:
+inputs go up once, the nine output planes come down once, and every
+intermediate (the resampled grid, fi/fj, tile origins, rate stack)
+stays on device.
 
 AGL tile fallback: tracks that span more than one DEM tile cannot use
-the single-tile Pallas kernel.  The unfused path detects this from the
-interpolated indices on the host (a forced device->host sync); here the
+the single-tile Pallas kernel.  The standalone ``ops.agl_lookup``
+detects this from the interpolated indices on the host; here the
 caller proves the single-tile property BEFORE launching — the interp
 output is a convex combination of the raw knots, so knot extents bound
 it — and tile-crossing buckets compile the oracle gather variant
@@ -81,24 +76,23 @@ def _pipeline(dem, t_in, v_in, count_in, t_out, count_out,
     else:
         interp = jnp.moveaxis(
             ref.track_interp_ref(t_in, v_in, count_in, t_out), 2, 1)
-    # Stage-boundary barrier: the unfused path materializes the interp
-    # result on the host before the AGL/rates stages consume it, so its
-    # f32 roundings are those of the standalone ops.  Without the
-    # barrier XLA may contract interp's epilogue into downstream FMAs
-    # and drift the fused outputs an ulp off the unfused golden path.
-    # (On TPU the stage is a pallas_call boundary anyway; this costs
-    # nothing material and buys bit-stable fused==unfused numerics.)
+    # Stage-boundary barrier: it pins the interp result's f32 roundings
+    # to those of the standalone ops (``ops.track_interp`` feeding
+    # ``ops.agl_lookup`` / ``ops.dynamic_rates``) and of the
+    # ``use_pallas=False`` graph, which the tests compare against.
+    # Without it XLA may contract interp's epilogue into downstream
+    # FMAs and drift the fused outputs an ulp off both.
     interp = jax.lax.optimization_barrier(interp)           # (B, 3, K)
     lat = interp[:, 0]
     lon = interp[:, 1]
     alt = interp[:, 2]
 
     # 2. DEM fractional indices from the affine grid — previously host
-    #    numpy between two kernel launches; now VPU elementwise.  The
-    #    optimization barrier pins the rounding at this former stage
-    #    boundary: without it XLA may fuse the affine math into the AGL
-    #    kernel's tile-local index FMAs and drift an ulp off the
-    #    unfused path (amplified by the local terrain gradient).
+    #    VPU elementwise.  The optimization barrier pins the rounding of
+    #    fi/fj to that of the standalone ``ops.agl_lookup`` inputs:
+    #    without it XLA may fuse the affine math into the AGL kernel's
+    #    tile-local index FMAs and drift an ulp off them (amplified by
+    #    the local terrain gradient).
     fi = (jnp.clip(lat, lat_min, lat_max) - lat_min) * cells_per_deg
     fj = (jnp.clip(lon, lon_min, lon_max) - lon_min) * cells_per_deg
     fi = jnp.clip(fi, 0.0, H - 1.001)
@@ -114,7 +108,7 @@ def _pipeline(dem, t_in, v_in, count_in, t_out, count_out,
     #    runtime per-row select (`lax.cond`/`where` mixing the two) is
     #    deliberately avoided: XLA contracts the mixed graphs
     #    differently and the selected values drift an ulp off the
-    #    standalone kernels, breaking fused==unfused bit-equality.
+    #    standalone kernels.
     if use_pallas and not agl_oracle:
         dem_p = jnp.pad(dem, ((0, _next_mult(H, TILE_H) - H),
                               (0, _next_mult(W, TILE_W) - W)))

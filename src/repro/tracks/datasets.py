@@ -20,6 +20,10 @@ Two products per dataset:
     the discrete-event simulator benchmarks; and
   * real, scaled-down CSV files on disk (synthetic ADS-B/radar
     observations) — drive the real workflow end to end.
+
+Beside them, :func:`synth_items` makes in-memory archives with a chosen
+segment-length mix (:data:`WORKLOADS`), shaped as the segment pipeline
+reads them.
 """
 
 from __future__ import annotations
@@ -27,6 +31,7 @@ from __future__ import annotations
 import dataclasses
 import math
 import os
+import zlib
 from typing import Optional
 
 import numpy as np
@@ -510,3 +515,81 @@ def write_scaled_dataset(root: str, spec: ScaledDatasetSpec,
             f.write("\n".join(rows) + "\n")
         paths.append(path)
     return paths
+
+
+# ---------------------------------------------------------------------------
+# Segment-length mixes (in-memory archives for the segment pipeline).
+# ---------------------------------------------------------------------------
+
+#: Segment-duration distributions (seconds on the 1 Hz grid, so a
+#: duration of d seconds is d+1 output points).  ``heavy_tail`` mirrors
+#: the paper's Fig 3 aerodrome case: mostly short segments, a long tail.
+WORKLOADS: dict[str, dict] = {
+    "heavy_tail": {"kind": "lognormal", "median_s": 100.0, "sigma": 0.6},
+    "uniform_mix": {"kind": "uniform", "low_s": 40.0, "high_s": 900.0},
+    "long_cruise": {"kind": "lognormal", "median_s": 700.0, "sigma": 0.25},
+}
+
+_DUR_CLIP = (15.0, 1023.0)
+
+
+@dataclasses.dataclass(frozen=True)
+class SegmentMixSpec:
+    """One synthetic segment mix: a :data:`WORKLOADS` distribution, the
+    number of archives and of segments in each, and the seed."""
+
+    workload: str = "heavy_tail"
+    n_archives: int = 10
+    segments_per_archive: int = 8
+    seed: int = 0
+
+    def __post_init__(self) -> None:
+        if self.workload not in WORKLOADS:
+            raise ValueError(f"unknown workload {self.workload!r}; "
+                             f"choose from {sorted(WORKLOADS)}")
+
+
+def synth_items(spec: SegmentMixSpec) -> list[tuple[dict, list[slice]]]:
+    """Deterministic synthetic archives for one segment mix.
+
+    Returns ``(obs, segs)`` pairs shaped exactly like
+    ``SegmentProcessor.read_observations`` + ``split_segments`` output,
+    so callers drive the real ``_process_many`` entry point (or write
+    the observations out as CSV) without touching the filesystem."""
+    from repro.tracks.segments import split_segments
+
+    w = WORKLOADS[spec.workload]
+    rng = np.random.default_rng(
+        spec.seed * 7919 + zlib.crc32(spec.workload.encode()) % 100003)
+    items = []
+    for a in range(spec.n_archives):
+        ts, lats, lons, alts = [], [], [], []
+        t = 0.0
+        for _ in range(spec.segments_per_archive):
+            if w["kind"] == "lognormal":
+                dur = rng.lognormal(np.log(w["median_s"]), w["sigma"])
+            else:
+                dur = rng.uniform(w["low_s"], w["high_s"])
+            dur = float(np.clip(dur, *_DUR_CLIP))
+            dt_obs = rng.uniform(3.0, 8.0)
+            n = max(10, int(dur / dt_obs) + 1)
+            gaps = rng.uniform(0.5, 1.5, n - 1)
+            gaps *= dur / gaps.sum()
+            seg_t = t + np.concatenate([[0.0], np.cumsum(gaps)])
+            ts.append(seg_t)
+            lat0 = rng.uniform(28.0, 47.0)
+            lon0 = rng.uniform(-120.0, -70.0)
+            lats.append(lat0 + np.cumsum(rng.normal(0, 1e-4, n)))
+            lons.append(lon0 + np.cumsum(rng.normal(0, 1e-4, n)))
+            alts.append(1500.0 + np.cumsum(rng.normal(0, 2.0, n)))
+            t = seg_t[-1] + 600.0           # force a segment break
+        obs = {
+            "time": np.concatenate(ts),
+            "lat": np.concatenate(lats),
+            "lon": np.concatenate(lons),
+            "alt": np.concatenate(alts),
+            "icao24": np.array([f"bench{a:02d}"]
+                               * sum(len(x) for x in ts)),
+        }
+        items.append((obs, split_segments(obs["time"])))
+    return items

@@ -262,13 +262,11 @@ class ScreenWorker:
     """
 
     def __init__(self, store_dir: str, *, h_thresh_m: float,
-                 v_thresh_m: float, backend: str = "pallas",
-                 pipeline: str = "fused", tracer=None):
+                 v_thresh_m: float, backend: str = "pallas", tracer=None):
         self.store_dir = store_dir
         self.h_thresh_m = h_thresh_m
         self.v_thresh_m = v_thresh_m
         self.backend = backend
-        self.pipeline = pipeline
         self.tracer = tracer
         self._proc: Optional[SegmentProcessor] = None
 
@@ -290,7 +288,7 @@ class ScreenWorker:
             self._proc = SegmentProcessor(
                 dem=SyntheticGlobeDEM(),
                 aerodromes=synthetic_aerodromes(n=64),
-                backend=self.backend, pipeline=self.pipeline)
+                backend=self.backend)
             self._proc.attach_tracer(self.tracer)
         return self._proc
 
@@ -349,13 +347,11 @@ class _CellBinEmitter(EdgeEmitter):
     """
 
     def __init__(self, store_dir: str, grid: GridSpec,
-                 config: ScreenConfig, *, backend: str = "pallas",
-                 pipeline: str = "fused"):
+                 config: ScreenConfig, *, backend: str = "pallas"):
         self.store_dir = store_dir
         self.grid = grid
         self.config = config
         self.backend = backend
-        self.pipeline = pipeline
         self.members: dict[str, list[str]] = {}   # cell -> all row ids
         self.pending: dict[str, list[str]] = {}   # cell -> unscreened ids
         self.gen: dict[str, int] = {}             # cell -> generations cut
@@ -366,7 +362,7 @@ class _CellBinEmitter(EdgeEmitter):
             self._proc = SegmentProcessor(
                 dem=SyntheticGlobeDEM(),
                 aerodromes=synthetic_aerodromes(n=64),
-                backend=self.backend, pipeline=self.pipeline)
+                backend=self.backend)
         return self._proc
 
     def feed(self, task: Task, result) -> list[Task]:
@@ -410,7 +406,6 @@ class TrackWorkflow:
                  organization: str = "largest_first",
                  poll_interval: float = 0.01,
                  backend: str = "pallas",
-                 pipeline: str = "fused",
                  exec_backend: str = "threads",
                  tasks_per_message: int = 1,
                  policy: str = "static",
@@ -483,7 +478,6 @@ class TrackWorkflow:
         self.organization = organization
         self.poll_interval = poll_interval
         self.backend = backend
-        self.pipeline = pipeline
         self.exec_backend = exec_backend
         self.tasks_per_message = tasks_per_message
         self.policy = policy
@@ -597,8 +591,7 @@ class TrackWorkflow:
         return ScreenWorker(self.store_dir,
                             h_thresh_m=self.screen_config.h_thresh_m,
                             v_thresh_m=self.screen_config.v_thresh_m,
-                            backend=self.backend, pipeline=self.pipeline,
-                            tracer=self.tracer)
+                            backend=self.backend, tracer=self.tracer)
 
     def _screen_tasks_full(self) -> list[Task]:
         """One task per multi-row cell over the *finished* store — the
@@ -609,7 +602,7 @@ class TrackWorkflow:
         proc = SegmentProcessor(
             dem=SyntheticGlobeDEM(),
             aerodromes=synthetic_aerodromes(n=64),
-            backend=self.backend, pipeline=self.pipeline)
+            backend=self.backend)
         proc.attach_tracer(self.tracer)
         rows = []
         for t in segment_tasks_from_store(self.store_dir,
@@ -733,7 +726,7 @@ class TrackWorkflow:
             dag.add_node("process", fn=SegmentProcessor(
                 dem=SyntheticGlobeDEM(),
                 aerodromes=synthetic_aerodromes(n=64),
-                backend=self.backend, pipeline=self.pipeline),
+                backend=self.backend),
                 tasks=process_tasks)
         store_tasks = None
         if run_store:
@@ -771,7 +764,7 @@ class TrackWorkflow:
                 # feeding them commit and process.
                 screen_emitter = _CellBinEmitter(
                     self.store_dir, self.screen_grid, self.screen_config,
-                    backend=self.backend, pipeline=self.pipeline)
+                    backend=self.backend)
                 dag.add_node("screen", fn=self._screen_worker())
                 dag.add_edge("process", "screen", emitter=screen_emitter)
             else:
@@ -915,7 +908,7 @@ class TrackWorkflow:
             proc = SegmentProcessor(
                 dem=SyntheticGlobeDEM(),
                 aerodromes=synthetic_aerodromes(n=64),
-                backend=self.backend, pipeline=self.pipeline)
+                backend=self.backend)
             if self.input == "store":
                 tasks = segment_tasks_from_store(self.store_dir,
                                                  granularity="shard")
@@ -1002,11 +995,6 @@ def main() -> None:
                          "self-scheduled phases; 'dag' streams tasks "
                          "between phases as dependencies resolve "
                          "(run_dag)")
-    ap.add_argument("--kernel-pipeline", default="fused",
-                    choices=["fused", "unfused"],
-                    help="segment hot path: fused device-resident "
-                         "bucketed pipeline, or the legacy three-launch "
-                         "baseline")
     ap.add_argument("--manager-shards", type=int, default=1,
                     help="coordinator shards for --pipeline dag (>1 "
                          "splits the pending queue by locality and "
@@ -1089,7 +1077,6 @@ def main() -> None:
         triple = TriplesConfig(nodes=args.nodes, nppn=args.nppn or 8)
     wf = TrackWorkflow(args.root, n_workers=args.workers,
                        exec_backend=args.backend,
-                       pipeline=args.kernel_pipeline,
                        tasks_per_message=args.tasks_per_message,
                        policy=args.policy,
                        poll_interval=0.005, triple=triple,

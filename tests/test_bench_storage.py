@@ -52,8 +52,8 @@ def test_storage_deterministic_metrics(storage_doc):
 
 def test_storage_canonical_bytes_reproducible(storage_doc):
     """Two same-seed campaign runs agree byte-for-byte after stripping
-    the nondeterministic keys.  Like the kernels artifact, the quick
-    tier gates wall-clock throughput, so ``checks`` (which record the
+    the nondeterministic keys.  The quick tier gates wall-clock
+    throughput, so ``checks`` (which record the
     measured actuals) are stripped too — ``metrics`` is the
     reproducible surface."""
     import json
@@ -118,8 +118,6 @@ def _fake_doc(schema, metric, values):
 
 def test_compare_dispatches_on_schema(storage_doc):
     assert default_metric(storage_doc) == "bytes_per_point"
-    assert default_metric({"schema": "repro.bench.kernels/v1"}) == \
-        "padded_fraction"
     assert default_metric({"schema": "repro.bench.campaign/v1"}) == \
         "job_seconds"
     with pytest.raises(ValueError):
@@ -137,21 +135,11 @@ def test_compare_storage_regression_gate(storage_doc):
     assert regs2 == []
 
 
-def test_compare_kernels_schema_dispatch():
-    old = _fake_doc("repro.bench.kernels/v1", "padded_fraction",
-                    {"a": 0.5, "b": 0.7})
-    new = _fake_doc("repro.bench.kernels/v1", "padded_fraction",
-                    {"a": 0.8, "b": 0.7})
-    rows, regs = compare_docs(old, new, threshold=0.10)
-    assert [r["name"] for r in regs] == ["a"]
-    assert rows[0]["metric"] == "padded_fraction"
-
-
 def test_compare_rejects_schema_mismatch(storage_doc):
-    kern = _fake_doc("repro.bench.kernels/v1", "padded_fraction",
-                     {"a": 0.5})
+    campaign = _fake_doc("repro.bench.campaign/v1", "job_seconds",
+                         {"a": 0.5})
     with pytest.raises(ValueError, match="different schemas"):
-        compare_docs(storage_doc, kern)
+        compare_docs(storage_doc, campaign)
 
 
 def test_compare_smoke_single_record():

@@ -1,8 +1,9 @@
-"""Fused on-device segment pipeline: pallas-vs-ref, fused-vs-unfused,
-bucketing/reassembly invariance, and the satellite vectorizations.
+"""Fused on-device segment pipeline: pallas-vs-ref, fused-vs-standalone
+kernels, bucketing/reassembly invariance, padding accounting, the
+synthetic segment mixes, and the satellite vectorizations.
 
-Tolerancing notes: the fused-vs-unfused comparison is gated at 1e-5 —
-the two paths run the same kernels on the same values (padding columns
+Tolerancing notes: the fused-vs-standalone comparison is gated at 1e-5
+— both run the same kernels on the same values (padding columns
 contribute exact zeros; stage boundaries are pinned with optimization
 barriers), so in practice they agree bitwise.  The pallas-vs-ref
 comparison tolerates ulp-level association differences (the AGL matmul
@@ -11,20 +12,23 @@ tracks drift east so dynamic-rate headings stay clear of the arctan2
 branch cut at +-pi.
 """
 
+import collections
 import zipfile
 
+import jax
 import numpy as np
 import pytest
 from hypothesis import given, settings
 from hypothesis import strategies as st
 
 from repro.geometry.aerodromes import Aerodrome, synthetic_aerodromes
-from repro.kernels import ops
+from repro.kernels import ops, ref
 from repro.kernels.segment_pipeline import FIELDS
+from repro.tracks.datasets import WORKLOADS, SegmentMixSpec, synth_items
 from repro.tracks.segments import (
-    _PLANE_ATTRS, BUCKET_SIZES, MAX_SEG_POINTS, ProcessedSegments,
-    SegmentProcessor, _empty, _pipeline_stats, _round_rows, bucket_width,
-    split_segments)
+    _PLANE_ATTRS, BUCKET_SIZES, MAX_SEG_POINTS, RESAMPLE_DT_S,
+    ProcessedSegments, SegmentProcessor, _empty, _pipeline_stats,
+    _round_rows, bucket_width, segment_shape, split_segments)
 
 # Equatorial test grid: f32 lat/lon ulp is ~60x smaller near 0 than at
 # CONUS latitudes, so central-difference rates don't amplify the
@@ -110,7 +114,8 @@ def test_process_segments_counts_compile_cache():
 
 
 # ---------------------------------------------------------------------------
-# Fused vs unfused on golden (real workflow) archives.
+# The fused path against the standalone kernels and the oracles, on
+# golden (real workflow) archives and on the synthetic segment mixes.
 # ---------------------------------------------------------------------------
 
 @pytest.fixture(scope="module")
@@ -126,54 +131,174 @@ def golden_archives(tmp_path_factory):
     return tasks
 
 
-def _processors():
-    aero = synthetic_aerodromes(n=64)
-    return (SegmentProcessor(aerodromes=aero, pipeline="fused"),
-            SegmentProcessor(aerodromes=aero, pipeline="unfused"))
+def _processor(**kw):
+    return SegmentProcessor(aerodromes=synthetic_aerodromes(n=64), **kw)
 
 
-def test_fused_matches_unfused_on_golden_archives(golden_archives):
-    """ISSUE 3 acceptance: fused == unfused within 1e-5 on golden
-    archives (the fused planes are narrower; the unfused tail beyond
-    the archive's bucket width must be pure padding)."""
-    fused, unfused = _processors()
-    fb = fused.process_batch(golden_archives)
-    ub = unfused.process_batch(golden_archives)
-    assert set(fb) == set(ub)
+#: The mixes' size in these tests: small, so tier-1 stays quick.
+MIX = dict(n_archives=3, segments_per_archive=4, seed=0)
+
+_oracle_agl = jax.jit(ref.agl_lookup_ref)
+
+
+def _standalone_planes(proc, items) -> list[dict]:
+    """The reference for the fused path: every segment of ``items`` in
+    one fixed (B, MAX_SEG_POINTS) tile through the three standalone ops,
+    with the DEM index math in host numpy between them.  AGL takes the
+    oracle gather for the rows the processor routes there
+    (``_may_span``).  Returns per archive a dict of (rows, 1024) planes
+    keyed like :data:`FIELDS`."""
+    segs = [(obs, s) for obs, ss in items for s in ss]
+    B, M = len(segs), MAX_SEG_POINTS
+    N = max(segment_shape(obs["time"], s)[0] for obs, s in segs)
+    t_in = np.zeros((B, N), np.float32)
+    v_in = np.zeros((B, 3, N), np.float32)
+    count_in = np.zeros((B,), np.int32)
+    t_out = np.zeros((B, M), np.float32)
+    count_out = np.zeros((B,), np.int32)
+    span = np.zeros((B,), bool)
+    for b, (obs, s) in enumerate(segs):
+        n, m = segment_shape(obs["time"], s)
+        sl = slice(s.start, s.start + n)
+        t = obs["time"][sl]
+        t_in[b, :n] = t - t[0]
+        t_in[b, n:] = (t[-1] - t[0]) + np.arange(1, N - n + 1)
+        for c, key in enumerate(("lat", "lon", "alt")):
+            v_in[b, c, :n] = obs[key][sl]
+        v_in[b, :, n:] = v_in[b, :, n - 1:n]
+        count_in[b] = n
+        t_out[b, :m] = np.arange(m) * RESAMPLE_DT_S
+        t_out[b, m:] = t_out[b, m - 1]
+        count_out[b] = m
+        span[b] = proc._may_span(obs["lat"][sl], obs["lon"][sl])
+
+    interp = np.asarray(ops.track_interp(t_in, v_in, count_in, t_out))
+    lat, lon, alt = interp[:, :, 0], interp[:, :, 1], interp[:, :, 2]
+    dem = proc.dem
+    H, W = proc._dem_f32.shape
+    fi = (np.clip(lat, dem.lat_min, dem.lat_max) - dem.lat_min) \
+        * dem.cells_per_deg
+    fj = (np.clip(lon, dem.lon_min, dem.lon_max) - dem.lon_min) \
+        * dem.cells_per_deg
+    fi = np.clip(fi.astype(np.float32), 0.0, np.float32(H - 1.001))
+    fj = np.clip(fj.astype(np.float32), 0.0, np.float32(W - 1.001))
+    agl = np.array(ops.agl_lookup(proc._dem_f32, fi, fj, alt))
+    if span.any():
+        agl[span] = np.asarray(_oracle_agl(proc._dem_f32, fi[span],
+                                           fj[span], alt[span]))
+    rates = np.asarray(ops.dynamic_rates(
+        np.stack([lat, lon, alt], axis=1), count_out, RESAMPLE_DT_S))
+    mask = np.arange(M)[None, :] < count_out[:, None]
+    planes = {"times": t_out, "lat": lat, "lon": lon, "alt_msl": alt,
+              "alt_agl": agl, "vrate": rates[:, 0], "gspeed": rates[:, 1],
+              "heading": rates[:, 2], "turn": rates[:, 3]}
+    out, row = [], 0
+    for _, ss in items:
+        rows = slice(row, row + len(ss))
+        out.append({k: (v * mask)[rows] for k, v in planes.items()})
+        row += len(ss)
+    return out
+
+
+def _case_items(case, golden_archives) -> list:
+    if case == "golden":
+        proc = SegmentProcessor()
+        items = []
+        for task in golden_archives:
+            obs = proc.read_observations(task.payload)
+            if obs:
+                items.append((obs, split_segments(obs["time"])))
+        return items
+    if case == "tile_border":
+        return _reassembly_items("mixed_buckets")
+    return synth_items(SegmentMixSpec(workload=case, **MIX))
+
+
+@pytest.mark.parametrize("case", ["golden", "tile_border",
+                                  *sorted(WORKLOADS)])
+def test_fused_matches_ref(case, golden_archives):
+    """The fused pipeline == the standalone kernels composed with host
+    hops between them, within 1e-5, on the golden archives, on tracks
+    that cross a DEM tile border, and on each synthetic segment mix;
+    the planes are narrower than a fixed 1024 tile, whose tail is pure
+    padding.  Against the pure-jnp oracles (``backend='ref'``) it holds
+    the positions and the vertical rate to the op-level tolerances of
+    test_process_segments_pallas_matches_ref_across_buckets; the
+    horizontal rates are left out, as the arctan2 branch cut amplifies
+    an ulp of interp there.  Its padding beats a fixed (B, 1024) tile
+    unless every segment already fills the widest bucket."""
+    items = _case_items(case, golden_archives)
+    fused = _processor()
+    got = fused._process_many(items)
+    want = _standalone_planes(fused, items)
+    oracle = _processor(backend="ref")._process_many(items)
     compared = 0
-    for tid in fb:
-        f, u = fb[tid], ub[tid]
-        assert f.icao24 == u.icao24
-        assert f.airspace == u.airspace
-        np.testing.assert_array_equal(f.count, u.count)
-        w = f.times.shape[1]
-        for attr in ATTRS:
-            a, b = getattr(f, attr), getattr(u, attr)
-            if a.size:
-                np.testing.assert_allclose(a, b[:, :w], atol=1e-5,
-                                           rtol=1e-5, err_msg=attr)
-                assert not b[:, w:].any()
-                compared += 1
+    for (obs, segs), f, w, r in zip(items, got, want, oracle):
+        assert f.icao24 == r.icao24 == [str(obs["icao24"][s.start])
+                                        for s in segs]
+        assert f.airspace == r.airspace
+        np.testing.assert_array_equal(f.count, r.count)
+        np.testing.assert_array_equal(
+            f.count, [segment_shape(obs["time"], s)[1] for s in segs])
+        width = f.times.shape[1]
+        for plane, attr in _PLANE_ATTRS:
+            a = getattr(f, attr)
+            if not a.size:
+                continue
+            np.testing.assert_allclose(a, w[plane][:, :width], atol=1e-5,
+                                       rtol=1e-5, err_msg=attr)
+            assert not w[plane][:, width:].any()
+            assert getattr(r, attr).shape == a.shape
+            if plane not in ("gspeed", "heading", "turn"):
+                np.testing.assert_allclose(
+                    a, getattr(r, attr), rtol=1e-3,
+                    atol=0.5 if plane == "vrate" else 1e-2, err_msg=attr)
+            compared += 1
     assert compared > 0
-    assert fused.last_stats["padded_fraction"] < \
-        unfused.last_stats["padded_fraction"]
+    stats = fused.last_stats
+    fixed = (stats["n_segments"] * MAX_SEG_POINTS - stats["valid_points"]) \
+        / stats["valid_points"]
+    if case == "long_cruise":
+        assert set(stats["bucket_rows"]) == {MAX_SEG_POINTS}
+    else:
+        assert stats["padded_fraction"] < fixed
 
 
-def test_fused_zero_intermediate_transfers(golden_archives):
-    fused, unfused = _processors()
-    ops.reset_pipeline_stats()
-    fused.process_batch(golden_archives)
-    assert ops.get_pipeline_stats()["intermediate_transfers"] == 0
-    ops.reset_pipeline_stats()
-    unfused.process_batch(golden_archives[:2])
-    # interp down, fi/fj up, agl down, rates down — per batch
-    assert ops.get_pipeline_stats()["intermediate_transfers"] == 4
+def test_fused_launches_no_standalone_kernel(golden_archives, monkeypatch):
+    """The fused path makes one device call per bucket and never calls
+    the standalone ops, each of which is a round trip through the host."""
+    def launched(*a, **kw):
+        raise AssertionError("the fused path called a standalone op")
+
+    for name in ("track_interp", "agl_lookup", "dynamic_rates"):
+        monkeypatch.setattr(ops, name, launched)
+    out = _processor().process_batch(golden_archives)
+    assert any(len(ps) for ps in out.values())
+
+
+def test_pipeline_counters_match_last_stats(golden_archives):
+    """One ``process_batch`` counts one compile hit or miss per device
+    call it makes (the counter ``pipeline_calls.process`` reads); the
+    same batch again compiles nothing new."""
+    proc = _processor()
+
+    def calls():
+        st = ops.get_pipeline_stats()
+        return st["compile_hits"], st["compile_misses"]
+
+    h0, m0 = calls()
+    proc.process_batch(golden_archives)
+    h1, m1 = calls()
+    n = proc.last_stats["pipeline_calls"]
+    assert n > 0 and (h1 + m1) - (h0 + m0) == n
+    proc.process_batch(golden_archives)
+    h2, m2 = calls()
+    assert m2 == m1 and h2 - h1 == n > 0
 
 
 def test_read_observations_golden_zip_roundtrip(golden_archives):
     """The vectorized zip/CSV parse yields sorted, finite columns."""
-    proc, _ = _processors()
-    obs = proc.read_observations(golden_archives[0].payload)
+    obs = _processor().read_observations(golden_archives[0].payload)
     if not obs:
         pytest.skip("first archive empty")
     assert (np.diff(obs["time"]) >= 0).all()
@@ -222,18 +347,82 @@ def _synth_archive(rng, n_segs):
 
 def test_fused_handles_zero_segment_archives():
     """An items entry with no segments yields an empty ProcessedSegments
-    from both pipelines (the fused path must not choke on empty rows)."""
+    (the fused path must not choke on empty rows)."""
     rng = np.random.default_rng(3)
     full = _synth_archive(rng, 2)
     empty = ({"time": np.array([0.0, 1.0]), "lat": np.zeros(2),
               "lon": np.zeros(2), "alt": np.zeros(2),
               "icao24": np.array(["x", "x"])}, [])
-    for pipeline in ("fused", "unfused"):
-        proc = SegmentProcessor(pipeline=pipeline)
-        out = proc._process_many([full, empty])
-        assert len(out) == 2
-        assert len(out[0]) == 2
-        assert len(out[1]) == 0
+    out = SegmentProcessor()._process_many([full, empty])
+    assert len(out) == 2
+    assert len(out[0]) == 2
+    assert len(out[1]) == 0
+
+
+# ---------------------------------------------------------------------------
+# The synthetic segment mixes and the bucket table's padding bound.
+# ---------------------------------------------------------------------------
+
+TINY = SegmentMixSpec(workload="heavy_tail", n_archives=2,
+                      segments_per_archive=3, seed=5)
+
+
+def test_synth_items_deterministic_and_segmented():
+    items_a = synth_items(TINY)
+    items_b = synth_items(TINY)
+    assert len(items_a) == TINY.n_archives
+    for (oa, sa), (ob, sb) in zip(items_a, items_b):
+        assert sa == sb and len(sa) == TINY.segments_per_archive
+        for k in oa:
+            assert (oa[k] == ob[k]).all()
+
+
+def test_bad_spec_rejected():
+    with pytest.raises(ValueError):
+        SegmentMixSpec(workload="nope")
+
+
+def test_metrics_deterministic_for_fixed_seed():
+    """Two runs of one seed's mix: the same ``last_stats`` and the same
+    planes, bit for bit."""
+    runs = []
+    for _ in range(2):
+        proc = SegmentProcessor()
+        runs.append((proc._process_many(synth_items(TINY)),
+                     proc.last_stats))
+    (out_a, stats_a), (out_b, stats_b) = runs
+    assert stats_a == stats_b
+    for a, b in zip(out_a, out_b):
+        for attr in ATTRS + ("count",):
+            assert getattr(a, attr).tobytes() == getattr(b, attr).tobytes()
+
+
+@pytest.mark.parametrize("mix", sorted(WORKLOADS))
+def test_bucket_padding_bound(mix):
+    """Each segment lands in the smallest bucket that holds it, so a
+    bucket wastes under 2x on any segment longer than the narrowest
+    bucket; the allocation is the buckets' rounded rows times their
+    widths.  On the heavy-tailed mix that cuts the padding at least 5x
+    against one fixed 1024-wide tile."""
+    items = synth_items(SegmentMixSpec(workload=mix, **MIX))
+    proc = SegmentProcessor()
+    proc._process_many(items)
+    stats = proc.last_stats
+    recs = proc._records(items)
+    for rec in recs:
+        k = max(rec.n, rec.m)
+        assert rec.width == bucket_width(k)
+        assert k <= rec.width
+        assert rec.width < 2 * k or rec.width == BUCKET_SIZES[0]
+    rows = collections.Counter((rec.width, rec.may_span) for rec in recs)
+    assert stats["pipeline_calls"] == len(rows)
+    assert stats["allocated_points"] == sum(_round_rows(c) * w
+                                            for (w, _), c in rows.items())
+    assert stats["valid_points"] == sum(rec.m for rec in recs)
+    fixed = (len(recs) * MAX_SEG_POINTS - stats["valid_points"]) \
+        / stats["valid_points"]
+    if mix == "heavy_tail":
+        assert fixed / stats["padded_fraction"] >= 5.0
 
 
 @settings(max_examples=8, deadline=None)
@@ -280,7 +469,7 @@ def _per_row_reassemble(self, items, records, buckets, fetched, allocated
         bucket_rows[int(width)] = bucket_rows.get(int(width), 0) \
             + len(ix)
     self.last_stats = _pipeline_stats(
-        "fused", self.backend, len(records), int(valid),
+        self.backend, len(records), int(valid),
         int(allocated), bucket_rows, len(buckets))
 
     out_list: list[ProcessedSegments] = []

@@ -67,8 +67,7 @@ def test_dag_byte_identical_to_barrier(barrier_wf, dag_wf):
 def test_candidates_equal_brute_force(barrier_wf):
     """The workflow's grid-screened candidates are exactly the brute
     force all-pairs set over the same store-derived rows."""
-    proc = SegmentProcessor(backend=barrier_wf.backend,
-                            pipeline=barrier_wf.pipeline)
+    proc = SegmentProcessor(backend=barrier_wf.backend)
     rows = []
     for t in segment_tasks_from_store(barrier_wf.store_dir,
                                       granularity="shard"):
@@ -93,3 +92,32 @@ def test_screen_resumes_when_artifact_missing(barrier_wf):
 def test_screen_requires_store_input(tmp_path):
     with pytest.raises(ValueError, match="store"):
         TrackWorkflow(str(tmp_path), screen=True, input="zip")
+
+
+def test_workflow_keeps_benchmark_hooks():
+    """The chip benchmark's screen driver subclasses ``TrackWorkflow``:
+    it overrides the private methods it names in ``HOOKS`` and builds
+    the workflow with keywords.  Read both from the driver's source, so
+    renaming a hook or a keyword fails here, on the CPU, and not first
+    in a run on the chip."""
+    import ast
+    import inspect
+    path = os.path.join(os.path.dirname(__file__), "..", "chipbench",
+                        "drivers", "screen.py")
+    with open(path) as f:
+        tree = ast.parse(f.read())
+    hooks = next(ast.literal_eval(node.value) for node in ast.walk(tree)
+                 if isinstance(node, ast.Assign)
+                 and any(getattr(t, "id", None) == "HOOKS"
+                         for t in node.targets))
+    assert {"_screen_tasks_full", "_run_phase", "_save_ckpt"} <= set(hooks)
+    for hook in hooks:
+        assert callable(getattr(TrackWorkflow, hook, None)), hook
+    calls = [node for node in ast.walk(tree)
+             if isinstance(node, ast.Call)
+             and isinstance(node.func, ast.Call)
+             and getattr(node.func.func, "id", None) == "_workflow_class"]
+    assert len(calls) == 1
+    keywords = {kw.arg for kw in calls[0].keywords}
+    assert {"input", "screen", "n_workers"} <= keywords
+    inspect.signature(TrackWorkflow).bind("root", **dict.fromkeys(keywords))
