@@ -84,7 +84,7 @@ from repro.tracks.organize import Organizer, organize_tasks_from_dir
 from repro.tracks.registry import synthetic_registry
 from repro.tracks.segments import (
     SegmentProcessor, segment_tasks_from_archive_tree,
-    segment_tasks_from_store, split_segments)
+    segment_tasks_from_store)
 
 
 @dataclasses.dataclass
@@ -246,19 +246,24 @@ class ScreenWorker:
     """Self-scheduled encounter-screen task: one spatial-hash cell.
 
     The task payload is a JSON doc ``{"cell", "all", "new"}`` naming the
-    cell and its member row ids.  The worker re-reads each member track
-    from the columnar store (``store://...#track=<id>``), re-derives its
-    ScreenRows through the fused segment pipeline (deterministic, so
-    recomputation after a checkpoint kill is exact), screens the single
-    cell with the fused kernel, and returns the candidate dicts.  With
-    ``new != all`` (a streaming-DAG generation) only pairs touching a
-    new row are emitted.  Picklable for the processes backend; the
-    SegmentProcessor is built lazily per process.
+    cell and its member row ids.  The worker reads the cell's member
+    tracks from the columnar store in one grouped read
+    (:meth:`repro.store.reader.TrackStore.read_tracks`: each shard unit
+    once), re-derives their ScreenRows in one fused segment-pipeline
+    pass over all of them (one device call per bucket of the whole cell;
+    a track's planes do not depend on its batch, and the pipeline is
+    deterministic, so recomputation after a checkpoint kill is exact),
+    screens the single cell with the fused kernel, and returns the
+    candidate dicts.  With ``new != all`` (a streaming-DAG generation)
+    only pairs touching a new row are emitted.  Picklable for the
+    processes backend; the SegmentProcessor is built lazily per process.
 
     With a ``tracer`` (a pickled copy drops it: worker processes emit no
-    spans) each member read is a ``store_decode`` span and its
-    re-derivation ``segments.*`` spans, then per track ``screen.rows``
-    (``rows_from_track``) and per cell ``screen.kernel``.
+    spans) the grouped read is one ``store_decode`` span per shard
+    touched and the pass its ``segments.*`` spans, then one
+    ``screen.rows`` span per task (``rows_from_track``; counters
+    ``rows`` kept and ``tracks``, the members with segments derived
+    together) and one ``screen.kernel``.
     """
 
     def __init__(self, store_dir: str, *, h_thresh_m: float,
@@ -302,20 +307,16 @@ class ScreenWorker:
         tracks = sorted({rid.rsplit("#", 1)[0] for rid in wanted})
         proc = self._processor()
         tr = self.tracer
-        rows = []
-        for tid in tracks:
-            uri = make_store_uri(self.store_dir, track=tid)
-            obs = proc.read_observations(uri)
-            segs = split_segments(obs["time"])
-            if not segs:
-                continue
-            ps = proc.process_arrays(obs, segs)
-            with stage(tr, "screen.rows", "task") as st:
-                n = len(rows)
-                rows.extend(r for r in rows_from_track(tid, obs, segs, ps)
-                            if r.row_id in wanted)
-                if tr is not None:
-                    st.extra = {"rows": len(rows) - n}
+        read = proc._store_read(self.store_dir,
+                                lambda st: st.read_tracks(tracks))
+        members = [(tid, *read[tid]) for tid in tracks if read[tid][1]]
+        procd = proc._process_triples(members)
+        with stage(tr, "screen.rows", "task") as st:
+            rows = [r for tid, obs, segs in members
+                    for r in rows_from_track(tid, obs, segs, procd[tid])
+                    if r.row_id in wanted]
+            if tr is not None:
+                st.extra = {"rows": len(rows), "tracks": len(members)}
         new = set(doc["new"])
         with stage(tr, "screen.kernel", "task") as st:
             cands, stats = screen_cells(
