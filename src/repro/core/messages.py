@@ -90,6 +90,14 @@ class Message:
     # tasks by the worker.
     task_spans: tuple[tuple[float, float, float], ...] = ()
     error: Optional[str] = None
+    # Worker processes only (repro.runtime.transports.WorkerPool): an
+    # ASSIGN's ``trace`` asks the worker to record its stage spans, which
+    # come back as ``events`` (repro.obs event tuples) in its DONE or
+    # FAILED; ``job`` numbers the pool's run_job call the message
+    # belongs to, so a late reply of an earlier job is told apart.
+    trace: bool = False
+    events: tuple = ()
+    job: int = 0
     sent_at: float = dataclasses.field(default_factory=time.monotonic)
 
 

@@ -8,6 +8,7 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 
 def track_interp_ref(t_in: jax.Array, v_in: jax.Array, count: jax.Array,
@@ -106,7 +107,9 @@ def flash_attention_ref(q: jax.Array, k: jax.Array, v: jax.Array, *,
     return jnp.einsum("bhts,bhsd->bhtd", p, vv.astype(jnp.float32))
 
 
-_SCREEN_BIG = jnp.float32(1e30)
+#: A host scalar, not a device array: importing the module initialises
+#: no JAX backend (worker processes import it and must not).
+_SCREEN_BIG = np.float32(1e30)
 
 
 def encounter_screen_ref(lat: jax.Array, lon: jax.Array, alt: jax.Array,

@@ -51,7 +51,11 @@ profiler trace taken alongside shows each stage on the device trace's
 clock.  The threads backend binds each worker thread to its track
 (``w<i>``) and the ids of the task(s) it runs (:meth:`Tracer.bind`);
 a stage span takes both from that binding, so its cause is its task's
-``exec`` span.  Stage spans of one thread are siblings, never nested.
+``exec`` span.  A worker process of the processes backend binds a ring
+of its own the same way and sends its events back with the task's DONE;
+the manager's ring takes them in (:meth:`Tracer.extend`), on the one
+``time.monotonic`` clock of the host.  Stage spans of one thread are
+siblings, never nested.
 
 The ring is bounded (``capacity`` events); overflow evicts the oldest
 event and counts it in :attr:`Tracer.dropped` — a saturated trace is
@@ -146,6 +150,18 @@ class Tracer:
         the stage spans it emits; ``track=None`` unbinds it."""
         self._bound.track = track
         self._bound.task_ids = task_ids
+
+    def bound_task(self):
+        """The first task id the calling thread is bound to, or None."""
+        ids = getattr(self._bound, "task_ids", ())
+        return ids[0] if ids else None
+
+    def extend(self, events) -> None:
+        """Append events recorded elsewhere (a worker process's ring),
+        tuples of the same layout, on the same ``time.monotonic``."""
+        events = tuple(events)
+        self._events.extend(events)
+        self.emitted += len(events)
 
     # -- read side ---------------------------------------------------------
 

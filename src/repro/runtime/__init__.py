@@ -4,7 +4,10 @@ One self-scheduling core (protocol.SchedulerCore) over three backends:
 
   * threads    — in-process worker threads (transports.ThreadTransport)
   * processes  — multiprocessing workers, real NPPN-style process
-                 isolation (transports.ProcessTransport)
+                 isolation (transports.ProcessTransport over a
+                 transports.WorkerPool, which a driver may keep across
+                 jobs; device calls go to the pool's owner in the
+                 manager's process)
   * sim        — the calibrated discrete-event engine at full LLSC scale
                  (sim.simulate_self_scheduling)
 
@@ -24,7 +27,7 @@ from repro.runtime.protocol import (
     DEFAULT_POLL_INTERVAL_S, ManagerCheckpoint, SchedulerCore, ShardedCore,
     drive, manager_shard, partition_tasks_by_locality)
 from repro.runtime.transports import (
-    ProcessTransport, ThreadTransport, Transport, worker_loop)
+    ProcessTransport, ThreadTransport, Transport, WorkerPool, worker_loop)
 from repro.runtime.sim import (
     DEFAULT_POLL_S, merge_tasks_per_message, simulate_self_scheduling,
     simulate_static)
@@ -40,7 +43,7 @@ __all__ = [
     "POLICIES", "POLICY_NAMES", "PhaseNode", "ProcessTransport",
     "RunResult", "SchedulerCore", "SchedulingPolicy", "ShardedCore",
     "SimTaskRecord", "StreamingDAG", "ThreadTransport", "Transport",
-    "WorkerSpeedModel",
+    "WorkerPool", "WorkerSpeedModel",
     "WorkerStats", "drive", "get_policy", "manager_shard",
     "merge_tasks_per_message", "partition_tasks_by_locality", "run_dag",
     "run_job", "run_service", "simulate_self_scheduling",

@@ -32,7 +32,8 @@ from repro.runtime.protocol import (
     DEFAULT_POLL_INTERVAL_S, ManagerCheckpoint, SchedulerCore, ShardedCore,
     drive)
 from repro.runtime.result import RunResult
-from repro.runtime.transports import TRANSPORTS
+from repro.runtime.transports import (
+    TRANSPORTS, ProcessTransport, WorkerPool)
 from repro.runtime import sim as _sim
 
 BACKENDS = ("threads", "processes", "sim")
@@ -83,6 +84,7 @@ def run_job(tasks: Sequence[Task],
             worker_slow_factor: Optional[dict[str, float]] = None,
             legacy_launch_penalty: float = 1.0,
             mp_context: Optional[str] = None,
+            pool: Optional[WorkerPool] = None,
             tracer: Optional[Any] = None) -> RunResult:
     """Run a self-scheduled job on the chosen execution backend.
 
@@ -140,8 +142,23 @@ def run_job(tasks: Sequence[Task],
     backend a worker function with an ``attach_tracer(tracer)`` method
     (returning the tracer it had) gets the tracer for the job, so its
     stage spans (:func:`repro.obs.stage`) land in the same ring under
-    the worker's track and task id; the processes backend's workers
-    cannot share the ring and emit no stage spans.
+    the worker's track and task id.  On the processes backend each
+    worker process records its stage spans for a traced job in a ring
+    of its own and returns them in its DONE messages, which put them in
+    ``tracer`` under the same track and task ids; the device owner adds
+    its ``device.serve`` spans.
+
+    ``pool`` runs a processes job on a launched
+    :class:`~repro.runtime.transports.WorkerPool` and leaves it running
+    for the next job: its worker function (``fn`` may be omitted), its
+    worker count, and the worker settings it was launched with
+    (``batch_fn``, heartbeats, ``worker_fail_after``,
+    ``worker_slow_factor``, the start method).  Without one, a processes
+    job launches a pool of its own and closes it at the end.  A worker
+    function with device calls (``serve_device`` / ``attach_device``,
+    e.g. :class:`repro.tracks.segments.SegmentProcessor`) runs them
+    through the pool's device owner in this process, so a job whose
+    manager holds the chip runs on worker processes too.
 
     ``speculative`` re-issues the longest-in-flight task to idle
     workers once the queue drains (at most ``speculation_max_copies``
@@ -169,6 +186,21 @@ def run_job(tasks: Sequence[Task],
     if backend not in BACKENDS:
         raise ValueError(f"unknown backend {backend!r}; "
                          f"choose from {BACKENDS}")
+    if pool is not None:
+        if backend != "processes":
+            raise ValueError("a WorkerPool runs the processes backend")
+        if fn is None:
+            fn = pool.fn
+        if n_workers is None:
+            n_workers = len(pool.worker_ids)
+        if (batch_fn is not None or worker_fail_after
+                or worker_slow_factor or mp_context is not None
+                or (failure_timeout is not None
+                    and pool.heartbeat_interval is None)):
+            raise ValueError(
+                "a job on a WorkerPool runs the pool's own function on "
+                "its workers, with the settings the pool was launched "
+                "with (heartbeats for a failure_timeout among them)")
     if triple is not None:
         if n_workers is None:
             n_workers = max(triple.worker_processes, 1)
@@ -262,22 +294,25 @@ def run_job(tasks: Sequence[Task],
         # Live backends: wall-clock domain, attached before the drive
         # loop so the queued-at-attach instants precede the first ASSIGN.
         core.attach_tracer(tracer)
-    if batch_fn is None:
-        batch_fn = getattr(fn, "process_batch", None)
-    heartbeat = (failure_timeout / 3 if failure_timeout is not None else None)
-    transport_cls = TRANSPORTS[backend]
-    kwargs: dict[str, Any] = {}
-    if backend == "processes" and mp_context is not None:
-        kwargs["mp_context"] = mp_context
     attach = None
     if backend == "threads" and tracer is not None:
-        kwargs["tracer"] = tracer
         attach = getattr(fn, "attach_tracer", None)
-    transport = transport_cls(
-        n_workers, fn, batch_fn=batch_fn, poll_interval=poll_interval,
-        heartbeat_interval=heartbeat, worker_fail_after=worker_fail_after,
-        worker_slow_factor=worker_slow_factor,
-        **kwargs)
+    if pool is not None:
+        transport = ProcessTransport(n_workers, fn, pool=pool,
+                                     tracer=tracer)
+    else:
+        if batch_fn is None:
+            batch_fn = getattr(fn, "process_batch", None)
+        kwargs: dict[str, Any] = {}
+        if backend == "processes" and mp_context is not None:
+            kwargs["mp_context"] = mp_context
+        transport = TRANSPORTS[backend](
+            n_workers, fn, batch_fn=batch_fn, poll_interval=poll_interval,
+            heartbeat_interval=(failure_timeout / 3
+                                if failure_timeout is not None else None),
+            worker_fail_after=worker_fail_after,
+            worker_slow_factor=worker_slow_factor, tracer=tracer,
+            **kwargs)
     prev = attach(tracer) if attach is not None else None
     try:
         return drive(core, transport,

@@ -199,6 +199,9 @@ class SegmentProcessor:
         #: Optional repro.obs.Tracer (attach_tracer): stage spans of the
         #: fused path and of its stores' decodes.
         self.tracer = None
+        #: In a worker process of the processes backend, its line to the
+        #: device owner (attach_device); None runs serve_device here.
+        self._device = None
         self._dem_f32 = self.dem.elevation_m.astype(np.float32)
         self._dem_grid = (self.dem.lat_min, self.dem.lat_max,
                           self.dem.lon_min, self.dem.lon_max,
@@ -209,14 +212,43 @@ class SegmentProcessor:
             self._aero_lon = np.array([a.lon for a in self.aerodromes])
             self._aero_cls = [a.airspace_class for a in self.aerodromes]
 
+    def __getstate__(self) -> dict:
+        state = dict(self.__dict__)
+        state.update(_stores={}, tracer=None, _device=None)
+        return state
+
     def attach_tracer(self, tracer):
         """Emit stage spans into ``tracer`` (None: stop); returns the
         tracer attached before.  ``run_job`` calls this for a traced
-        threads job."""
+        threads job, a worker process for each traced message."""
         prev, self.tracer = self.tracer, tracer
         for store in self._stores.values():
             store.tracer = tracer
         return prev
+
+    def attach_device(self, client):
+        """Send each bucket's device call through ``client`` instead of
+        running :meth:`serve_device` here; returns the client before.
+
+        A worker process of the processes backend
+        (:class:`repro.runtime.transports.WorkerPool`) attaches its line
+        to the device owner, ``client(args, counters)``: the owner runs
+        the manager's own copy's :meth:`serve_device` on ``args`` and
+        returns the fetched planes.  The worker builds no device array."""
+        prev, self._device = self._device, client
+        return prev
+
+    def serve_device(self, t_in, v_in, count_in, t_out, count_out,
+                     may_span) -> dict[str, np.ndarray]:
+        """One packed bucket through the fused pipeline, fetched to the
+        host: ONE jit'd call and one device->host fetch per plane, the
+        pipeline's only downward transfer.  The device owner runs this
+        for the worker processes."""
+        out = ops.process_segments(
+            self._dem_f32, t_in, v_in, count_in, t_out, count_out,
+            grid=self._dem_grid, dt=RESAMPLE_DT_S, backend=self.backend,
+            agl_oracle=may_span)
+        return {k: np.asarray(v) for k, v in out.items()}
 
     # -- io -------------------------------------------------------------
 
@@ -446,7 +478,8 @@ class SegmentProcessor:
         segmentation into records (``segments.records``), per bucket the
         host packing (``segments.pack``) and the device call up to its
         fetch (``segments.device``, with the bucket's valid and
-        allocated points), and the reassembly (``segments.reassemble``,
+        allocated points; in a worker process, the round trip to the
+        device owner), and the reassembly (``segments.reassemble``,
         with the ``segments`` reassembled and the ``gathers`` made: one
         per pair of source bucket and width group).
         """
@@ -504,18 +537,16 @@ class SegmentProcessor:
                 if tr is not None:
                     st.extra = {"rows": bk, "width": width}
             with stage(tr, "segments.device", "task") as st:
-                out = ops.process_segments(
-                    self._dem_f32, t_in, v_in, count_in, t_out, count_out,
-                    grid=self._dem_grid, dt=RESAMPLE_DT_S,
-                    backend=self.backend, agl_oracle=may_span)
-                # ONE device->host fetch per bucket — the pipeline's only
-                # downward transfer.
-                fetched.append((idxs, {k: np.asarray(v)
-                                       for k, v in out.items()}))
-                del out         # release the device buffers in this span
+                args = (t_in, v_in, count_in, t_out, count_out, may_span)
+                counters = {"valid": int(count_out[:bk].sum()),
+                            "allocated": bp * width}
+                # The device buffers are freed inside serve_device, in
+                # this span; a worker process's owner fetches for it.
+                fetched.append((idxs, self.serve_device(*args)
+                                if self._device is None
+                                else self._device(args, counters)))
                 if tr is not None:
-                    st.extra = {"valid": int(count_out[:bk].sum()),
-                                "allocated": bp * width}
+                    st.extra = counters
 
         with stage(tr, "segments.reassemble", "task") as st:
             out_list, gathers = self._reassemble(items, records, buckets,

@@ -5,8 +5,10 @@ behind the unified self-scheduling runtime
 (:func:`repro.runtime.run_job`), with a JSON phase checkpoint so a
 killed job resumes where it left off.  The execution backend is
 pluggable: ``threads`` (default) or ``processes`` (real NPPN-style
-process isolation, CPU only: on a TPU one process owns the chip, so
-the device phases refuse it); periodic *mid-phase* manager checkpoints mean a
+process isolation; on a TPU the process phase's worker processes send
+their device calls to the device owner in this process, which holds the
+chip, and the screen phase and the dag pipeline refuse it); periodic
+*mid-phase* manager checkpoints mean a
 kill-and-restart resumes inside a phase, not just at phase boundaries.
 This is the real (scaled-down) counterpart of the simulated full-scale
 benchmarks.
@@ -258,8 +260,10 @@ class ScreenWorker:
     only pairs touching a new row are emitted.  Picklable for the
     processes backend; the SegmentProcessor is built lazily per process.
 
-    With a ``tracer`` (a pickled copy drops it: worker processes emit no
-    spans) the grouped read is one ``store_decode`` span per shard
+    With a ``tracer`` (a pickled copy drops it; on the processes backend
+    each worker process attaches a ring of its own for a traced message,
+    whose spans return to the manager's ring in the message's DONE) the
+    grouped read is one ``store_decode`` span per shard
     touched and the pass its ``segments.*`` spans, then one
     ``screen.rows`` span per task (``rows_from_track``; counters
     ``rows`` kept and ``tracks``, the members with segments derived
@@ -447,12 +451,15 @@ class TrackWorkflow:
         if policy not in POLICY_NAMES:
             raise ValueError(f"unknown scheduling policy {policy!r}; "
                              f"choose from {list(POLICY_NAMES)}")
-        if exec_backend == "processes" and device.on_tpu():
+        if exec_backend == "processes" and (screen or mode == "dag") \
+                and device.on_tpu():
             raise ValueError(
-                "exec_backend='processes' cannot run the device phases "
-                "(process, screen) on a TPU: each worker process would "
-                "need the chip, which one process owns at a time (see "
-                "ROADMAP B.1); use exec_backend='threads'")
+                "exec_backend='processes' runs only the process phase on "
+                "a TPU: its worker processes send each bucket's device "
+                "call to the device owner in this process, which holds "
+                "the chip, but the screen phase's kernel and the dag "
+                "pipeline's device tasks have no path to the owner yet "
+                "(see ROADMAP B.1); use exec_backend='threads' for them")
         if elastic:
             if exec_backend != "threads":
                 raise ValueError("--elastic needs exec_backend='threads' "

@@ -294,7 +294,7 @@ def test_tracer_is_attached_on_threads_only():
     fn = _Attachable()
     r = run_job(tasks, fn, backend="processes", n_workers=2, tracer=tr)
     assert r.completed_ids == {t.task_id for t in tasks}
-    assert fn.attached == []                   # workers cannot share it
+    assert fn.attached == []       # each worker process attaches its own
     assert all(e[6]["worker_cpu"] > 0.0 for e in _spans(tr.events, "exec"))
 
 

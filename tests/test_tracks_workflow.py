@@ -147,14 +147,20 @@ def test_workflow_runs_on_process_backend(tmp_path):
 
 
 def test_process_backend_refused_on_tpu(tmp_path, monkeypatch):
-    """On a TPU one process owns the chip: the device phases refuse
-    worker processes up front, before any phase runs."""
+    """On a TPU one process owns the chip: the process phase's worker
+    processes reach it through the device owner, but the screen phase
+    and the dag pipeline have no path to it and refuse worker processes
+    up front, before any phase runs."""
     from repro import device
     monkeypatch.setattr(device, "on_tpu", lambda: True)
-    with pytest.raises(ValueError, match=r"\(process, screen\).*B\.1"):
+    with pytest.raises(ValueError, match=r"screen phase.*B\.1"):
         TrackWorkflow(str(tmp_path), exec_backend="processes",
                       input="store", screen=True)
+    with pytest.raises(ValueError, match=r"dag pipeline.*B\.1"):
+        TrackWorkflow(str(tmp_path), exec_backend="processes",
+                      mode="dag")
     assert not os.listdir(tmp_path)
+    TrackWorkflow(str(tmp_path), exec_backend="processes", input="store")
     TrackWorkflow(str(tmp_path), exec_backend="threads")
 
 
